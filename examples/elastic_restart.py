@@ -2,12 +2,12 @@
 
   PYTHONPATH=src python examples/elastic_restart.py
 
-Phase 1 trains on 1 device and checkpoints. Phase 2 (a subprocess with 8
-fake devices) restores the SAME checkpoint onto a 2x4 (data x model) mesh via
+Phase 1 trains on 1 device and checkpoints. Phase 2 (8 virtual CPU
+devices) restores the SAME checkpoint onto a 2x4 (data x model) mesh via
 restore(shardings=...) and continues training — the cluster shrank/grew and
-training just continues.
+training just continues.  Each phase is a child process and this parent never
+imports JAX, so on a TPU host phase 1 holds the chip alone and releases it.
 """
-import json
 import os
 import pathlib
 import subprocess
@@ -32,12 +32,14 @@ PHASE1 = textwrap.dedent("""
 
 PHASE2 = textwrap.dedent("""
     import os
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses, jax
     from repro.configs import get_config
     from repro.configs.base import SparseConfig
     from repro.checkpoint import restore
     from repro.data import batch_for
+    from repro.launch.mesh import make_local_mesh
     from repro.launch.sharding import batch_shardings, state_shardings
     from repro.optim import LRSchedule, OptConfig
     from repro.training import init_train_state, make_train_step
@@ -46,7 +48,7 @@ PHASE2 = textwrap.dedent("""
                               sparse=SparseConfig(sparsity=0.8, delta_t=20))
     opt = OptConfig(kind="adam", grad_clip=1.0, weight_decay=0.0)
     like, axes, _ = init_train_state(jax.random.PRNGKey(0), cfg, opt)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_local_mesh(2, 4)
     sh = state_shardings(like, axes, mesh)
     state, step = restore(like, r"%s/ckpt", shardings=sh)
     print(f"phase2: restored step {step} onto {len(jax.devices())} devices, mesh {dict(mesh.shape)}")

@@ -154,8 +154,7 @@ def _fwd_kernel(
         # per-row logsumexp residual for the backward recomputation; rows
         # with NO live key get +1e30 so the backward's exp(s - lse) is
         # exactly zero for them instead of overflowing
-        lse = jnp.where(l_raw > 0.0, m_ref[...] + jnp.log(l), -NEG_INF)
-        lse_ref[0, :] = lse[:, 0]
+        lse_ref[0] = jnp.where(l_raw > 0.0, m_ref[...] + jnp.log(l), -NEG_INF)
 
 
 def _dq_kernel(
@@ -190,9 +189,9 @@ def _dq_kernel(
         )
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse_ref[0, :][:, None])  # masked slots: exp(-inf) = 0
+        p = jnp.exp(s - lse_ref[0])  # masked slots: exp(-inf) = 0
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, :][:, None]) * scale
+        ds = p * (dp - delta_ref[0]) * scale
         if t is not None:
             ds = ds * (1.0 - t * t)
         acc_ref[...] += jnp.dot(
@@ -245,12 +244,12 @@ def _dkv_kernel(
         )
         if mask is not None:
             s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse_ref[0, :][:, None])
+        p = jnp.exp(s - lse_ref[0])
         dv_acc[...] += jnp.dot(
             p.T.astype(do.dtype), do, preferred_element_type=jnp.float32
         )
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, :][:, None]) * scale
+        ds = p * (dp - delta_ref[0]) * scale
         if t is not None:
             ds = ds * (1.0 - t * t)
         dk_acc[...] += jnp.dot(
@@ -291,7 +290,10 @@ def _fwd_call(q, k, v, kv_idx, kv_cnt, bq, bk, causal, window, q_offset, sk,
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda b, qb, s, *_: (b, qb, 0)),
-            pl.BlockSpec((1, bq), lambda b, qb, s, *_: (b, qb)),
+            # per-row lse (and delta in the backward) carry a trailing unit
+            # dim: Mosaic needs a block's last two dims divisible by (8, 128)
+            # or equal to the array's, which a (1, bq) row block is not
+            pl.BlockSpec((1, bq, 1), lambda b, qb, s, *_: (b, qb, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
@@ -308,7 +310,7 @@ def _fwd_call(q, k, v, kv_idx, kv_cnt, bq, bk, causal, window, q_offset, sk,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((BH, Sqp, d), q.dtype),
-            jax.ShapeDtypeStruct((BH, Sqp), jnp.float32),
+            jax.ShapeDtypeStruct((BH, Sqp, 1), jnp.float32),
         ],
         interpret=interpret,
     )(kv_idx, kv_cnt, q, k, v)
@@ -374,8 +376,7 @@ def _paged_kernel(
         l_raw = l_ref[...]
         l = jnp.maximum(l_raw, EPS)
         o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse = jnp.where(l_raw > 0.0, m_ref[...] + jnp.log(l), NEG_INF)
-        lse_ref[0, 0, :] = lse[:, 0]
+        lse_ref[0, 0] = jnp.where(l_raw > 0.0, m_ref[...] + jnp.log(l), NEG_INF)
 
 
 def _paged_call(q, pk, pv, kv_idx, table, ctx, bq, scale, softcap, interpret):
@@ -408,7 +409,7 @@ def _paged_call(q, pk, pv, kv_idx, table, ctx, bq, scale, softcap, interpret):
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, d), q_map),
-            pl.BlockSpec((1, 1, bq), lambda b, h, qb, s, *_: (b, h, qb)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, qb, s, *_: (b, h, qb, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
@@ -424,7 +425,7 @@ def _paged_call(q, pk, pv, kv_idx, table, ctx, bq, scale, softcap, interpret):
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Sqp, d), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sqp), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sqp, 1), jnp.float32),
         ],
         interpret=interpret,
     )(kv_idx, table, ctx, q, pk, pv)
@@ -478,7 +479,7 @@ def flash_attention_paged(
         softcap=float(softcap),
         interpret=interpret,
     )
-    return o[:, :, :Sq], lse[:, :, :Sq]
+    return o[:, :, :Sq], lse[:, :, :Sq, 0]
 
 
 def _dq_call(q, k, v, do, lse, delta, kv_idx, kv_cnt, bq, bk, causal, window,
@@ -491,7 +492,7 @@ def _dq_call(q, k, v, do, lse, delta, kv_idx, kv_cnt, bq, bk, causal, window,
         return (b, qb, 0)
 
     def row_map(b, qb, s, *_):
-        return (b, qb)
+        return (b, qb, 0)
 
     def kv_map(b, qb, s, idx_ref, cnt_ref):
         # same GQA fold as the forward: K/V stay at their true KV-head count
@@ -505,8 +506,8 @@ def _dq_call(q, k, v, do, lse, delta, kv_idx, kv_cnt, bq, bk, causal, window,
             pl.BlockSpec((1, bk, d), kv_map),
             pl.BlockSpec((1, bk, d), kv_map),
             pl.BlockSpec((1, bq, d), q_map),
-            pl.BlockSpec((1, bq), row_map),
-            pl.BlockSpec((1, bq), row_map),
+            pl.BlockSpec((1, bq, 1), row_map),
+            pl.BlockSpec((1, bq, 1), row_map),
         ],
         out_specs=pl.BlockSpec((1, bq, d), q_map),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
@@ -537,7 +538,7 @@ def _dkv_call(q, k, v, do, lse, delta, q_idx, q_cnt, bq, bk, causal, window,
         return (b * kv_groups + gm, _clamp(idx_ref, cnt_ref, kb, s), 0)
 
     def row_map(b, kb, gm, s, idx_ref, cnt_ref):
-        return (b * kv_groups + gm, _clamp(idx_ref, cnt_ref, kb, s))
+        return (b * kv_groups + gm, _clamp(idx_ref, cnt_ref, kb, s), 0)
 
     def kv_map(b, kb, gm, s, *_):
         return (b, kb, 0)
@@ -550,8 +551,8 @@ def _dkv_call(q, k, v, do, lse, delta, q_idx, q_cnt, bq, bk, causal, window,
             pl.BlockSpec((1, bk, d), kv_map),
             pl.BlockSpec((1, bk, d), kv_map),
             pl.BlockSpec((1, bq, d), q_map),
-            pl.BlockSpec((1, bq), row_map),
-            pl.BlockSpec((1, bq), row_map),
+            pl.BlockSpec((1, bq, 1), row_map),
+            pl.BlockSpec((1, bq, 1), row_map),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, d), kv_map),
@@ -607,7 +608,8 @@ def _flash_bwd(bq, bk, causal, window, q_offset, sk, scale, softcap,
     q, k, v, out, lse, kv_idx, kv_cnt, q_idx, q_cnt = res
     # delta_i = sum_j p_ij * dp_ij = rowsum(do * o): O(S*d) in jnp, f32
     delta = jnp.sum(
-        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
+        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1,
+        keepdims=True,
     )
     dq = _dq_call(
         q, k, v, do, lse, delta, kv_idx, kv_cnt, bq, bk, causal, window,
@@ -755,7 +757,7 @@ def flash_attention(
             scale=float(1.0 / np.sqrt(d)), softcap=float(softcap),
             kv_groups=kv_groups, interpret=interpret,
         )
-        return out[:, :Sq], lse[:, :Sq]
+        return out[:, :Sq], lse[:, :Sq, 0]
     out = _flash_jit(
         q, k, v, kv_idx, kv_cnt, q_idx, q_cnt, bq=bq, bk=bk,
         causal=bool(causal), window=int(window), q_offset=q_offset, sk=Sk,

@@ -1,8 +1,10 @@
 """Jitted public wrappers around the Pallas kernels.
 
-``interpret=None`` auto-selects: compiled on TPU, interpret (python-executed
-kernel bodies) elsewhere — the CPU CI validates kernel semantics against
-ref.py; the BlockSpec tiling targets TPU v5e VMEM (128-aligned tiles).
+``interpret=None`` auto-selects (``auto_interpret``): compiled on TPU,
+interpret (python-executed kernel bodies) on the CPU backend, where the tests
+validate kernel semantics against ref.py; any other backend raises.  The
+BlockSpec tiling targets TPU v5e VMEM (128-aligned tiles);
+tests/test_tpu_compile.py compiles the main-path kernels for a described v5e.
 
 Both linear wrappers are fully differentiable (the underlying kernels carry
 custom-VJP Pallas backward passes) and accept NON-ALIGNED leading dims: the
@@ -76,7 +78,18 @@ __all__ = [
 
 
 def auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """False on TPU (compiled Mosaic kernels), True on the CPU backend
+    (interpret mode, for tests).  Any other backend raises: running kernel
+    bodies in Python there would hide a mis-detected device."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run compiled on 'tpu' or interpreted on 'cpu'; "
+        f"the default backend is {backend!r}"
+    )
 
 
 def _round_up(n: int, mult: int) -> int:

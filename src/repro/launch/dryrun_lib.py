@@ -242,15 +242,10 @@ def _lower_cost(cfg, shape, mesh, opt_cfg, kind: str | None = None):
     """(flops, bytes, coll_bytes) per device for this exact cfg."""
     setup = _SETUPS[kind or shape.kind]
     jitted, abstract = setup(cfg, shape, mesh, opt_cfg)
-    # ambient mesh for in-model SP constraints; jax<0.5 has no set_mesh but
-    # Mesh itself is a context manager with the same effect there
-    ambient = jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-    with ambient:
+    with jax.set_mesh(mesh):  # ambient mesh for in-model SP constraints
         lowered = jitted.lower(*abstract)
         compiled = lowered.compile()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax<0.5: list of per-device dicts
-        ca = ca[0] if ca else {}
     coll = collective_bytes(compiled.as_text())
     return {
         "flops": float(ca.get("flops", 0.0)),

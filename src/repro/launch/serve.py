@@ -33,11 +33,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs import get_config
-from ..core import apply_masks
+from ..core import build_pack_state
 from ..data import batch_for
-from ..models import attn_schedules, init_caches, init_lm, lm_decode, lm_prefill
-from ..training import init_train_state
-from ..optim import OptConfig
+from ..models import attn_schedules, lm_decode, lm_prefill
+from ..training import init_weights
+from .compile_cache import enable_compile_cache
 
 __all__ = [
     "serve_session",
@@ -195,15 +195,22 @@ def configure_kernel(cfg, *, kernel=None, block=None, attn_kernel=None):
 def init_serving_state(cfg, seed: int = 0):
     """Fresh weights ready to serve -> (params, masks, pack).
 
-    Kernel-dispatch modes serve RAW weights + masks (w*m never materialized;
-    block_sparse also carries the host-packed tight-grid topology built by
-    init_train_state — a restored checkpoint carries its own).  Dense mode
-    pre-masks once and serves effective weights (masks/pack None).
+    The same params and masks ``init_train_state`` draws from this seed, and
+    nothing a server does not read: no optimizer state, no Top-KAST backward
+    supersets.  Kernel-dispatch modes serve RAW weights + masks (w*m never
+    materialized; block_sparse also carries the host-packed tight-grid
+    topology — a restored checkpoint carries its own).  Dense mode serves the
+    weights as init left them, zeroed off-mask (masks/pack None).
     """
-    state, _, _ = init_train_state(jax.random.PRNGKey(seed), cfg, OptConfig())
-    if cfg.sparse.kernel in ("masked", "block_sparse"):
-        return state["params"], state["masks"], state.get("pack")
-    return apply_masks(state["params"], state["masks"]), None, None
+    k_params, k_masks, _ = jax.random.split(jax.random.PRNGKey(seed), 3)
+    params, masks, _, _ = init_weights(k_params, k_masks, cfg)
+    sp = cfg.sparse
+    if sp.kernel not in ("masked", "block_sparse"):
+        return params, None, None
+    pack = None
+    if sp.kernel == "block_sparse" and sp.block_shape is not None:
+        pack = build_pack_state(masks, sp.block_shape, slack=sp.pack_width_slack)
+    return params, masks, pack
 
 
 def main():
@@ -274,6 +281,7 @@ def main():
         help="write Prometheus text-exposition metrics here after the run",
     )
     args = p.parse_args()
+    enable_compile_cache()
     cfg = configure_kernel(
         get_config(args.arch, smoke=args.smoke), kernel=args.kernel,
         block=args.block, attn_kernel=args.attn_kernel,

@@ -42,6 +42,7 @@ from ..training import (
     refresh_pack,
     snip_init,
 )
+from .compile_cache import enable_compile_cache
 
 __all__ = ["train_loop", "main"]
 
@@ -298,6 +299,7 @@ def main():
              "(rewritten at log cadence)",
     )
     args = p.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     method = args.method

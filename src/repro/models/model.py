@@ -271,17 +271,18 @@ def _sp_constraint(x, cfg):
     """Megatron-style sequence parallelism: shard the residual stream's seq
     dim over the model axis between layers.  GSPMD then turns the TP psums
     into reduce-scatter + all-gather pairs (half the ICI bytes) and the remat
-    residual saves shrink by the TP degree.  Needs an ambient mesh
-    (jax.sharding.use_mesh) — the dry-run/train drivers provide one."""
+    residual saves shrink by the TP degree.  Takes the ambient mesh
+    (jax.set_mesh — the dry-run provides one); without one there is nothing
+    to shard over and x passes through.  Under a mesh, a constraint the mesh
+    cannot meet raises."""
     if not getattr(cfg, "seq_shard_activations", False):
+        return x
+    if jax.sharding.get_abstract_mesh().empty:
         return x
     from jax.sharding import PartitionSpec as P
 
     U = P.UNCONSTRAINED
-    try:
-        return jax.lax.with_sharding_constraint(x, P(U, "model", U))
-    except Exception:
-        return x  # no ambient mesh: constraint unavailable, stay unsharded
+    return jax.lax.with_sharding_constraint(x, P(U, "model", U))
 
 
 def _embed_inputs(params, cfg, batch):

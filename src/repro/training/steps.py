@@ -71,6 +71,7 @@ from ..optim import (
 
 __all__ = [
     "sparsity_map",
+    "init_weights",
     "init_train_state",
     "make_train_step",
     "make_rigl_step",
@@ -127,10 +128,15 @@ def needs_bwd_masks(sp) -> bool:
     )
 
 
-def init_train_state(key, cfg, opt_cfg: OptConfig, *, loss_fn=None):
-    """State dict: step/params/masks/opt/rng (+dense_mom for SNFS)."""
-    k1, k2, k3 = jax.random.split(key, 3)
-    params, axes, sparse_flags = init_lm(k1, cfg)
+def init_weights(k_params, k_masks, cfg):
+    """Fresh sparse weights -> (params, masks, axes, sparse_flags).
+
+    The weights and topology half of ``init_train_state`` (which passes the
+    first two of its three key splits), shared with serving's init
+    (launch/serve.py::init_serving_state), which needs no optimizer state.
+    Params are zeroed off-mask.
+    """
+    params, axes, sparse_flags = init_lm(k_params, cfg)
     if cfg.param_dtype == "bfloat16":
         # pure-bf16 weights (f32 optimizer master state lives in opt_state
         # unless OptConfig.state_dtype says otherwise) — needed to fit the
@@ -177,9 +183,18 @@ def init_train_state(key, cfg, opt_cfg: OptConfig, *, loss_fn=None):
                 )
         # block-aligned init when block mode is on, so the topology is
         # executable by the block-sparse kernel from the very first step
-        masks = init_masks(k2, params, smap, block_shape=sp.block_shape)
-        # zero-out masked weights at init so nnz(w) matches the mask
-        params = apply_masks(params, masks)
+        masks = init_masks(k_masks, params, smap, block_shape=sp.block_shape)
+        # zero-out masked weights at init so nnz(w) matches the mask; the
+        # donation masks in place, so init never holds two copies of params
+        params = jax.jit(apply_masks, donate_argnums=0)(params, masks)
+    return params, masks, axes, sparse_flags
+
+
+def init_train_state(key, cfg, opt_cfg: OptConfig, *, loss_fn=None):
+    """State dict: step/params/masks/opt/rng (+dense_mom for SNFS)."""
+    k1, k2, k3 = jax.random.split(key, 3)
+    params, masks, axes, sparse_flags = init_weights(k1, k2, cfg)
+    sp = cfg.sparse
     state = {
         "step": jnp.zeros((), jnp.int32),
         "params": params,

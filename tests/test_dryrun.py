@@ -40,7 +40,8 @@ MINI = textwrap.dedent("""
     import json
     import jax
     from repro.launch import dryrun_lib
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_local_mesh
+    mesh = make_local_mesh(2, 4)
     art = dryrun_lib.run_cell("h2o-danube-1.8b", "train_4k", mesh, save=False,
                               cfg_overrides={"n_layers": 2, "microbatches": 1})
     print(json.dumps({

@@ -32,6 +32,22 @@ def test_masked_matmul_sweep(shape, dtype):
     )
 
 
+
+@pytest.mark.parametrize(
+    "backend,interpret", [("cpu", True), ("tpu", False), ("gpu", None)]
+)
+def test_auto_interpret_by_backend(monkeypatch, backend, interpret):
+    """Interpret mode on the CPU backend only, compiled kernels on TPU, and
+    an error anywhere else: no silent Python kernel bodies on a device."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            ops.auto_interpret()
+    else:
+        assert ops.auto_interpret() is interpret
+
 @pytest.mark.parametrize("density", [0.0, 0.25, 0.75, 1.0])
 def test_block_sparse_matmul_densities(density):
     M, K, N, bk, bn = 128, 512, 256, 128, 128

@@ -120,3 +120,48 @@ def test_grok_softcap_serve_parity():
         params, cfg, paged, {"tokens": tokens[:, ctx:]}, table, jnp.int32(ctx)
     )
     assert float(jnp.max(jnp.abs(logits_s[:, 0] - full[:, -1]))) < 2e-4
+
+
+@pytest.mark.parametrize("kernel", ["dense", "masked", "block_sparse"])
+def test_init_serving_state_matches_train_init(kernel):
+    """Serving init holds the params and masks init_train_state draws from
+    the same seed and nothing else (no optimizer state, no backward
+    supersets), and prefills to the training state's logits."""
+    import numpy as np
+
+    from repro.configs.base import SparseConfig
+    from repro.launch.serve import configure_kernel, init_serving_state
+    from repro.optim import OptConfig
+    from repro.training import init_train_state
+
+    cfg = dataclasses.replace(
+        get_config("h2o-danube-1.8b", smoke=True), dtype="float32",
+        sparse=SparseConfig(sparsity=0.8, method="rigl"),
+    )
+    cfg = configure_kernel(cfg, kernel=kernel, block=16)
+    params, masks, pack = init_serving_state(cfg, seed=2)
+    state, _, _ = init_train_state(jax.random.PRNGKey(2), cfg, OptConfig())
+
+    def same(a, b):
+        la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
+        assert len(la) == len(lb)
+        for x, y in zip(la, lb):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+    same(params, state["params"])
+    if kernel == "dense":
+        assert masks is None and pack is None
+        train_masks, train_pack = None, None
+    else:
+        same(masks, state["masks"])
+        train_masks, train_pack = state["masks"], state.get("pack")
+        assert "bwd_masks" in state  # kernel-dispatch RigL trains with them
+        assert (pack is None) == (kernel == "masked")
+
+    tokens = jax.random.randint(jax.random.PRNGKey(5), (1, 24), 0, cfg.vocab_size)
+    want, _ = lm_prefill(
+        state["params"], cfg, {"tokens": tokens}, 32, masks=train_masks,
+        pack=train_pack,
+    )
+    got, _ = lm_prefill(params, cfg, {"tokens": tokens}, 32, masks=masks, pack=pack)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

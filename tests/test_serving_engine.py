@@ -319,9 +319,10 @@ def test_engine_eos_and_max_tokens_lifecycle():
     prompt = _prompt(cfg, 6, seed=3)
     ref = _lockstep_tokens(cfg, params, prompt, 12, 48)
 
-    # eos at the 4th generated token stops generation there (eos kept)
-    eos = ref[3]
-    assert eos not in ref[:3], "test prompt degenerate: eos appears earlier"
+    # eos = the first token of the reference stream that is new to it after
+    # the first position: generation stops at its first occurrence (eos kept)
+    stop = next(i for i in range(1, len(ref)) if ref[i] not in ref[:i])
+    eos = ref[stop]
     r_eos = Request(rid=0, tokens=prompt, max_new_tokens=12, eos_id=eos)
     # max_new_tokens=1 finishes straight from the prefill logits
     r_one = Request(rid=1, tokens=prompt, max_new_tokens=1)
@@ -329,7 +330,7 @@ def test_engine_eos_and_max_tokens_lifecycle():
     engine.submit(r_eos)
     engine.submit(r_one)
     stats = engine.run()
-    assert r_eos.generated == ref[:4]
+    assert r_eos.generated == ref[: stop + 1]
     assert r_one.generated == ref[:1]
     assert stats["requests"] == 2
 

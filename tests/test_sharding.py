@@ -91,6 +91,7 @@ DIST_SCRIPT = textwrap.dedent(
     from repro.configs import get_config
     from repro.configs.base import SparseConfig
     from repro.data import batch_for
+    from repro.launch.mesh import make_local_mesh
     from repro.launch.sharding import batch_shardings, state_shardings
     from repro.optim import LRSchedule, OptConfig
     from repro.training import init_train_state, make_train_step
@@ -106,7 +107,7 @@ DIST_SCRIPT = textwrap.dedent(
         losses = []
         step = make_train_step(cfg, opt, lr)
         if mesh_shape:
-            mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+            mesh = make_local_mesh(*mesh_shape)
             st_sh = state_shardings(state, axes, mesh)
             state = jax.device_put(state, st_sh)
             fn = jax.jit(step)
