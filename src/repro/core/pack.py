@@ -84,6 +84,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import REGISTRY, region
 from .masks import block_mask_of, path_name
 
 __all__ = [
@@ -170,6 +171,7 @@ def slack_width(width: int, worst: int, slack: float) -> int:
 def pack_entry(
     mask, block_shape, *, min_width: int = 0, min_row_width: int = 0,
     slack: float = 0.0, name: str = "?", bwd_mask=None, min_bwd_width: int = 0,
+    obs=None,
 ):
     """Host-pack ONE mask leaf into a PackState entry (CSC + CSR views).
 
@@ -193,16 +195,38 @@ def pack_entry(
     block granularity: a forward-active block missing from the wgrad grid
     would silently zero that block's gradient (the exact silent-wrong-answer
     class validate_pack exists to make loud).
-    """
-    from ..kernels.block_sparse_matmul import (
-        pack_block_mask,
-        pack_block_mask_rows,
-        pack_group_mask,
-        pack_group_mask_rows,
-    )
 
-    bm = np.asarray(block_mask_of(np.asarray(mask, bool), block_shape))
-    grouped = bm.ndim == 3
+    Its phases are ``region``s (obs/trace.py; ``obs`` an optional
+    Observability handle): ``repro.pack.to_host`` fetches the mask and
+    superset leaves (waiting for them) and counts the bytes in
+    ``repro_pack_bytes_to_host_total``; ``repro.pack.build`` packs in numpy;
+    ``repro.pack.to_device`` puts the packed arrays on the device.
+    """
+    with region("repro.pack.to_host", obs=obs):
+        m = np.asarray(mask, bool)
+        b = None if bwd_mask is None else np.asarray(bwd_mask, bool)
+    fetched = sum(x.nbytes for x in (mask, bwd_mask) if isinstance(x, jax.Array))
+    if fetched:
+        (obs.metrics if obs is not None else REGISTRY).counter(
+            "repro_pack_bytes_to_host_total",
+            "mask bytes fetched to the host to pack them",
+        ).inc(fetched)
+    with region("repro.pack.build", obs=obs):
+        host = _pack_host_entry(
+            m, b, block_shape, min_width=min_width,
+            min_row_width=min_row_width, slack=slack, name=name,
+            min_bwd_width=min_bwd_width,
+        )
+    with region("repro.pack.to_device", obs=obs):
+        return {k: jnp.asarray(v) for k, v in host.items()}
+
+
+def _pack_host_entry(m, b, block_shape, *, min_width, min_row_width, slack,
+                     name, min_bwd_width):
+    """pack_entry's numpy half: host masks -> the entry's arrays."""
+    from ..kernels.block_sparse_matmul import pack_host
+
+    bm = block_mask_of(m, block_shape)
     nkb, nnb = bm.shape[-2], bm.shape[-1]
     total = int(bm.sum())
     if total == 0:
@@ -219,22 +243,18 @@ def pack_entry(
     row_width = slack_width(
         max(int(bm.sum(axis=-1).max()), 1, min_row_width), nnb, slack
     )
-    if grouped:
-        idx, cnt = pack_group_mask(bm, max_count=width)
-        ridx, rcnt = pack_group_mask_rows(bm, max_count=row_width)
-    else:
-        idx, cnt = pack_block_mask(bm, max_count=width)
-        ridx, rcnt = pack_block_mask_rows(bm, max_count=row_width)
+    idx, cnt = pack_host(bm, width)
+    ridx, rcnt = pack_host(np.swapaxes(bm, -1, -2), row_width)
     entry = {
         "idx": idx,
         "cnt": cnt,
         "ridx": ridx,
         "rcnt": rcnt,
-        "nnz": jnp.int32(total),
-        "nkb": jnp.int32(nkb),
+        "nnz": np.int32(total),
+        "nkb": np.int32(nkb),
     }
-    if bwd_mask is not None:
-        bbm = np.asarray(block_mask_of(np.asarray(bwd_mask, bool), block_shape))
+    if b is not None:
+        bbm = block_mask_of(b, block_shape)
         if np.any(bm & ~bbm):
             raise PackIntegrityError(
                 f"PackState: layer {name!r} backward superset does not "
@@ -245,16 +265,14 @@ def pack_entry(
         bwidth = slack_width(
             max(int(bbm.sum(axis=-2).max()), 1, min_bwd_width), nkb, slack
         )
-        if grouped:
-            bidx, bcnt = pack_group_mask(bbm, max_count=bwidth)
-        else:
-            bidx, bcnt = pack_block_mask(bbm, max_count=bwidth)
-        entry |= {"bidx": bidx, "bcnt": bcnt, "bnnz": jnp.int32(int(bbm.sum()))}
+        bidx, bcnt = pack_host(bbm, bwidth)
+        entry |= {"bidx": bidx, "bcnt": bcnt, "bnnz": np.int32(int(bbm.sum()))}
     return entry
 
 
 def build_pack_state(
-    masks, block_shape, *, prev=None, slack: float = 0.0, bwd_masks=None
+    masks, block_shape, *, prev=None, slack: float = 0.0, bwd_masks=None,
+    obs=None,
 ):
     """Masks pytree -> PackState pytree (same structure; entry or None leaves).
 
@@ -268,6 +286,9 @@ def build_pack_state(
     bwd_masks: Top-KAST backward supersets mirroring masks; packed entries
     additionally carry the superset CSC (``bidx``/``bcnt``/``bnnz``) driving
     the wgrad grid (docs/training.md#topkast).
+    obs: optional Observability handle for pack_entry's phase regions.  The
+    leaves are packed one at a time, so a leaf's fetch waits only for that
+    leaf (a superset still being drawn overlaps the packing before it).
     """
     flat, treedef = jax.tree_util.tree_flatten_with_path(
         masks, is_leaf=lambda x: x is None
@@ -299,6 +320,7 @@ def build_pack_state(
             pack_entry(
                 m, block_shape, min_width=min_w, min_row_width=min_rw,
                 slack=slack, name=name, bwd_mask=bw, min_bwd_width=min_bw,
+                obs=obs,
             )
         )
     return jax.tree_util.tree_unflatten(treedef, entries)
@@ -327,7 +349,7 @@ def build_bwd_carrier(bwd_masks):
 
 
 def refresh_pack_state(
-    masks, block_shape, *, prev, slack: float = 0.0, bwd_masks=None
+    masks, block_shape, *, prev, slack: float = 0.0, bwd_masks=None, obs=None
 ):
     """Re-pack after a topology update (call right after every rigl_step).
 
@@ -336,7 +358,8 @@ def refresh_pack_state(
     every update.
     """
     return build_pack_state(
-        masks, block_shape, prev=prev, slack=slack, bwd_masks=bwd_masks
+        masks, block_shape, prev=prev, slack=slack, bwd_masks=bwd_masks,
+        obs=obs,
     )
 
 
@@ -571,8 +594,8 @@ def pack_stats(pack) -> dict[str, Any]:
 
 
 def publish_pack_gauges(metrics, pack) -> None:
-    """Set the kernel_* gauges on a metrics registry (repro.obs duck-typed —
-    no import, so core stays obs-free) from ``pack_stats``: runtime grid
+    """Set the kernel_* gauges on a metrics registry (duck-typed: any object
+    with ``gauge(name, help, labels)``) from ``pack_stats``: runtime grid
     fraction plus forward/superset block densities, per layer and under the
     ``_total`` aggregate label.  Both the serving engine (construction — its
     pack is engine-lifetime constant) and the trainer (every refresh_pack)

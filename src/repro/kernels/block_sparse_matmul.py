@@ -34,6 +34,12 @@ Packing comes in two flavours:
 Grid: (M/bm, N/bn, max_active_k); zero-count columns clamp to block 0 and are
 fully masked by @pl.when (the clamp keeps indices non-negative — see _clamp).
 
+Each ``pallas_call`` is named ``<variant>_<pass>``, and the name is its op's
+name in a compiled program and a profiler trace: ``block_sparse_matmul_fwd``,
+``_dx`` and ``_dw``, with the variants ``grouped_``, ``topkast_``,
+``topkast_grouped_``, ``fused_`` and ``fused_grouped_`` in front.  Every name
+holds ``block_sparse_matmul``, which is what trace readers match.
+
 Grouped variant (``grouped_block_sparse_matmul``): a leading group dim G is
 prepended to everything — x (G, M, K), w (G, K, N), stacked per-group packs
 (idx (G, N/bn, width), shared width = max over groups) — and the grid grows a
@@ -70,6 +76,7 @@ __all__ = [
     "pack_group_mask_rows",
     "pack_group_mask_traced",
     "pack_group_mask_rows_traced",
+    "pack_host",
     "unpack_block_mask",
 ]
 
@@ -145,6 +152,22 @@ def pack_block_mask_rows_traced(block_mask):
     return _pack_jnp(block_mask.T, block_mask.shape[1])
 
 
+def pack_host(block_mask, max_count=None):
+    """Host CSC pack, as numpy arrays, of a (K/bk, N/bn) block mask or,
+    per group at one shared width, of a (G, K/bk, N/bn) stack: what
+    ``pack_block_mask`` / ``pack_group_mask`` put on the device.  PackState
+    (core/pack.py) packs with it and uploads the arrays itself."""
+    bm = np.asarray(block_mask, bool)
+    if bm.ndim == 2:
+        return _pack_np(bm, max_count)
+    assert bm.ndim == 3, bm.shape
+    if max_count is None:
+        max_count = max(int(bm.sum(axis=1).max(initial=0)), 1)
+    packed = [_pack_np(b, max_count) for b in bm]
+    return (np.stack([i for i, _ in packed]),
+            np.stack([c for _, c in packed]))
+
+
 def pack_group_mask(block_masks, max_count=None):
     """Stacked per-group CSC pack of a (G, K/bk, N/bn) bool block-mask stack.
 
@@ -160,11 +183,7 @@ def pack_group_mask(block_masks, max_count=None):
     """
     bms = np.asarray(block_masks, bool)
     assert bms.ndim == 3, bms.shape
-    if max_count is None:
-        max_count = max(int(bms.sum(axis=1).max(initial=0)), 1)
-    packed = [_pack_np(b, max_count) for b in bms]
-    idx = np.stack([i for i, _ in packed])
-    cnt = np.stack([c for _, c in packed])
+    idx, cnt = pack_host(bms, max_count)
     return jnp.asarray(idx), jnp.asarray(cnt)
 
 
@@ -294,7 +313,8 @@ def _dw_kernel(idx_ref, cnt_ref, x_ref, g_ref, o_ref, acc_ref, *, n_m: int):
 # pallas_call wrappers
 # ---------------------------------------------------------------------------
 
-def _fwd_call(x, w, block_idx, block_cnt, bm, bn, bk, interpret):
+def _fwd_call(x, w, block_idx, block_cnt, bm, bn, bk, interpret,
+              name="block_sparse_matmul"):
     from jax.experimental.pallas import tpu as pltpu
 
     M, K = x.shape
@@ -323,10 +343,12 @@ def _fwd_call(x, w, block_idx, block_cnt, bm, bn, bk, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         interpret=interpret,
+        name=f"{name}_fwd",
     )(block_idx, block_cnt, x, w)
 
 
-def _dx_call(g, w, row_idx, row_cnt, bm, bn, bk, interpret, out_dtype):
+def _dx_call(g, w, row_idx, row_cnt, bm, bn, bk, interpret, out_dtype,
+             name="block_sparse_matmul"):
     from jax.experimental.pallas import tpu as pltpu
 
     M, N = g.shape
@@ -355,10 +377,12 @@ def _dx_call(g, w, row_idx, row_cnt, bm, bn, bk, interpret, out_dtype):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, K), out_dtype),
         interpret=interpret,
+        name=f"{name}_dx",
     )(row_idx, row_cnt, g, w)
 
 
-def _dw_call(x, g, block_idx, block_cnt, bm, bn, bk, interpret):
+def _dw_call(x, g, block_idx, block_cnt, bm, bn, bk, interpret,
+             name="block_sparse_matmul"):
     from jax.experimental.pallas import tpu as pltpu
 
     M, K = x.shape
@@ -391,6 +415,7 @@ def _dw_call(x, g, block_idx, block_cnt, bm, bn, bk, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nnb * max_k, bk, bn), jnp.float32),
         interpret=interpret,
+        name=f"{name}_dw",
     )(block_idx, block_cnt, x, g)
 
 
@@ -555,7 +580,8 @@ def _g_dw_kernel(idx_ref, cnt_ref, x_ref, g_ref, o_ref, acc_ref, *, n_m: int):
         ).astype(o_ref.dtype)[None, None]
 
 
-def _g_fwd_call(x, w, block_idx, block_cnt, bm, bn, bk, interpret):
+def _g_fwd_call(x, w, block_idx, block_cnt, bm, bn, bk, interpret,
+                name="grouped_block_sparse_matmul"):
     from jax.experimental.pallas import tpu as pltpu
 
     G, M, K = x.shape
@@ -584,10 +610,12 @@ def _g_fwd_call(x, w, block_idx, block_cnt, bm, bn, bk, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((G, M, N), x.dtype),
         interpret=interpret,
+        name=f"{name}_fwd",
     )(block_idx, block_cnt, x, w)
 
 
-def _g_dx_call(g_, w, row_idx, row_cnt, bm, bn, bk, interpret, out_dtype):
+def _g_dx_call(g_, w, row_idx, row_cnt, bm, bn, bk, interpret, out_dtype,
+               name="grouped_block_sparse_matmul"):
     from jax.experimental.pallas import tpu as pltpu
 
     G, M, N = g_.shape
@@ -616,10 +644,12 @@ def _g_dx_call(g_, w, row_idx, row_cnt, bm, bn, bk, interpret, out_dtype):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((G, M, K), out_dtype),
         interpret=interpret,
+        name=f"{name}_dx",
     )(row_idx, row_cnt, g_, w)
 
 
-def _g_dw_call(x, g_, block_idx, block_cnt, bm, bn, bk, interpret):
+def _g_dw_call(x, g_, block_idx, block_cnt, bm, bn, bk, interpret,
+               name="grouped_block_sparse_matmul"):
     from jax.experimental.pallas import tpu as pltpu
 
     G, M, K = x.shape
@@ -652,6 +682,7 @@ def _g_dw_call(x, g_, block_idx, block_cnt, bm, bn, bk, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((G, nnb * max_k, bk, bn), jnp.float32),
         interpret=interpret,
+        name=f"{name}_dw",
     )(block_idx, block_cnt, x, g_)
 
 
@@ -740,14 +771,16 @@ def _topkast_block_sparse_matmul(
     x, w, block_idx, block_cnt, row_idx, row_cnt, bwd_idx, bwd_cnt,
     bm, bn, bk, interpret,
 ):
-    return _fwd_call(x, w, block_idx, block_cnt, bm, bn, bk, interpret)
+    return _fwd_call(x, w, block_idx, block_cnt, bm, bn, bk, interpret,
+                     name="topkast_block_sparse_matmul")
 
 
 def _tk_fwd(
     x, w, block_idx, block_cnt, row_idx, row_cnt, bwd_idx, bwd_cnt,
     bm, bn, bk, interpret,
 ):
-    out = _fwd_call(x, w, block_idx, block_cnt, bm, bn, bk, interpret)
+    out = _fwd_call(x, w, block_idx, block_cnt, bm, bn, bk, interpret,
+                    name="topkast_block_sparse_matmul")
     return out, (x, w, block_idx, block_cnt, row_idx, row_cnt, bwd_idx, bwd_cnt)
 
 
@@ -759,8 +792,10 @@ def _tk_bwd(bm, bn, bk, interpret, res, g):
     # dx on the FORWARD topology (y only saw w ⊙ A), wgrad on the SUPERSET:
     # dw is exactly the dense gradient restricted to B's support, the
     # side-channel the rigl/snfs grow scores consume.
-    dx = _dx_call(g, w, row_idx, row_cnt, bm, bn, bk, interpret, x.dtype)
-    packed = _dw_call(x, g, bwd_idx, bwd_cnt, bm, bn, bk, interpret)
+    dx = _dx_call(g, w, row_idx, row_cnt, bm, bn, bk, interpret, x.dtype,
+                  name="topkast_block_sparse_matmul")
+    packed = _dw_call(x, g, bwd_idx, bwd_cnt, bm, bn, bk, interpret,
+                      name="topkast_block_sparse_matmul")
     dw = _scatter_packed_dw(packed, bwd_idx, bwd_cnt, nkb, bk, bn, w.dtype)
 
     z = lambda a: np.zeros(a.shape, jax.dtypes.float0)
@@ -815,14 +850,16 @@ def _topkast_grouped_block_sparse_matmul(
     x, w, block_idx, block_cnt, row_idx, row_cnt, bwd_idx, bwd_cnt,
     bm, bn, bk, interpret,
 ):
-    return _g_fwd_call(x, w, block_idx, block_cnt, bm, bn, bk, interpret)
+    return _g_fwd_call(x, w, block_idx, block_cnt, bm, bn, bk, interpret,
+                       name="topkast_grouped_block_sparse_matmul")
 
 
 def _gtk_fwd(
     x, w, block_idx, block_cnt, row_idx, row_cnt, bwd_idx, bwd_cnt,
     bm, bn, bk, interpret,
 ):
-    out = _g_fwd_call(x, w, block_idx, block_cnt, bm, bn, bk, interpret)
+    out = _g_fwd_call(x, w, block_idx, block_cnt, bm, bn, bk, interpret,
+                      name="topkast_grouped_block_sparse_matmul")
     return out, (x, w, block_idx, block_cnt, row_idx, row_cnt, bwd_idx, bwd_cnt)
 
 
@@ -831,8 +868,10 @@ def _gtk_bwd(bm, bn, bk, interpret, res, g):
     K, N = w.shape[1], w.shape[2]
     nkb = K // bk
 
-    dx = _g_dx_call(g, w, row_idx, row_cnt, bm, bn, bk, interpret, x.dtype)
-    packed = _g_dw_call(x, g, bwd_idx, bwd_cnt, bm, bn, bk, interpret)
+    dx = _g_dx_call(g, w, row_idx, row_cnt, bm, bn, bk, interpret, x.dtype,
+                    name="topkast_grouped_block_sparse_matmul")
+    packed = _g_dw_call(x, g, bwd_idx, bwd_cnt, bm, bn, bk, interpret,
+                        name="topkast_grouped_block_sparse_matmul")
     dw = jax.vmap(
         lambda p_, i_, c_: _scatter_packed_dw(p_, i_, c_, nkb, bk, bn, w.dtype)
     )(packed, bwd_idx, bwd_cnt)
@@ -939,7 +978,8 @@ def _dw_fused_kernel(
 
 
 def _dw_fused_call(
-    x, g, wg_idx, wg_cnt, w, mom, seed, mu, wd, sr, bm, bn, bk, interpret
+    x, g, wg_idx, wg_cnt, w, mom, seed, mu, wd, sr, bm, bn, bk, interpret,
+    name="fused_block_sparse_matmul",
 ):
     from jax.experimental.pallas import tpu as pltpu
 
@@ -980,6 +1020,7 @@ def _dw_fused_call(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nnb * max_k, bk, bn), jnp.float32),
         interpret=interpret,
+        name=f"{name}_dw",
     )(wg_idx, wg_cnt, seed, x, g, w, mom)
 
 
@@ -990,14 +1031,16 @@ def _fused_block_sparse_matmul(
     x, w, block_idx, block_cnt, row_idx, row_cnt, wg_idx, wg_cnt, mom, seed,
     mu, wd, sr, bm, bn, bk, interpret,
 ):
-    return _fwd_call(x, w, block_idx, block_cnt, bm, bn, bk, interpret)
+    return _fwd_call(x, w, block_idx, block_cnt, bm, bn, bk, interpret,
+                     name="fused_block_sparse_matmul")
 
 
 def _fbs_fwd(
     x, w, block_idx, block_cnt, row_idx, row_cnt, wg_idx, wg_cnt, mom, seed,
     mu, wd, sr, bm, bn, bk, interpret,
 ):
-    out = _fwd_call(x, w, block_idx, block_cnt, bm, bn, bk, interpret)
+    out = _fwd_call(x, w, block_idx, block_cnt, bm, bn, bk, interpret,
+                    name="fused_block_sparse_matmul")
     return out, (
         x, w, block_idx, block_cnt, row_idx, row_cnt, wg_idx, wg_cnt, mom, seed
     )
@@ -1010,7 +1053,8 @@ def _fbs_bwd(mu, wd, sr, bm, bn, bk, interpret, res, g):
     K = w.shape[0]
     nkb = K // bk
 
-    dx = _dx_call(g, w, row_idx, row_cnt, bm, bn, bk, interpret, x.dtype)
+    dx = _dx_call(g, w, row_idx, row_cnt, bm, bn, bk, interpret, x.dtype,
+                  name="fused_block_sparse_matmul")
     packed = _dw_fused_call(
         x, g, wg_idx, wg_cnt, w, mom, seed, mu, wd, sr, bm, bn, bk, interpret
     )
@@ -1115,7 +1159,8 @@ def _g_dw_fused_kernel(
 
 
 def _g_dw_fused_call(
-    x, g_, wg_idx, wg_cnt, w, mom, seed, mu, wd, sr, bm, bn, bk, interpret
+    x, g_, wg_idx, wg_cnt, w, mom, seed, mu, wd, sr, bm, bn, bk, interpret,
+    name="fused_grouped_block_sparse_matmul",
 ):
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1156,6 +1201,7 @@ def _g_dw_fused_call(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((G, nnb * max_k, bk, bn), jnp.float32),
         interpret=interpret,
+        name=f"{name}_dw",
     )(wg_idx, wg_cnt, seed, x, g_, w, mom)
 
 
@@ -1166,14 +1212,16 @@ def _fused_grouped_block_sparse_matmul(
     x, w, block_idx, block_cnt, row_idx, row_cnt, wg_idx, wg_cnt, mom, seed,
     mu, wd, sr, bm, bn, bk, interpret,
 ):
-    return _g_fwd_call(x, w, block_idx, block_cnt, bm, bn, bk, interpret)
+    return _g_fwd_call(x, w, block_idx, block_cnt, bm, bn, bk, interpret,
+                       name="fused_grouped_block_sparse_matmul")
 
 
 def _gfbs_fwd(
     x, w, block_idx, block_cnt, row_idx, row_cnt, wg_idx, wg_cnt, mom, seed,
     mu, wd, sr, bm, bn, bk, interpret,
 ):
-    out = _g_fwd_call(x, w, block_idx, block_cnt, bm, bn, bk, interpret)
+    out = _g_fwd_call(x, w, block_idx, block_cnt, bm, bn, bk, interpret,
+                      name="fused_grouped_block_sparse_matmul")
     return out, (
         x, w, block_idx, block_cnt, row_idx, row_cnt, wg_idx, wg_cnt, mom, seed
     )
@@ -1186,7 +1234,8 @@ def _gfbs_bwd(mu, wd, sr, bm, bn, bk, interpret, res, g):
     K = w.shape[1]
     nkb = K // bk
 
-    dx = _g_dx_call(g, w, row_idx, row_cnt, bm, bn, bk, interpret, x.dtype)
+    dx = _g_dx_call(g, w, row_idx, row_cnt, bm, bn, bk, interpret, x.dtype,
+                    name="fused_grouped_block_sparse_matmul")
     packed = _g_dw_fused_call(
         x, g, wg_idx, wg_cnt, w, mom, seed, mu, wd, sr, bm, bn, bk, interpret
     )

@@ -34,6 +34,10 @@ The padded variant (``tight=False``) runs the SAME kernels on a schedule
 whose width is padded to the dense worst case Sk/bk — bit-identical outputs,
 longer grid — mirroring the tight-vs-padded weight-pack duality.  ``ref.py``'s
 ``flash_attention_ref`` is the jnp oracle for all mask families.
+
+Each ``pallas_call`` is named, and the name is its op's name in a compiled
+program and a profiler trace: ``flash_fwd``, ``flash_bwd_dq``,
+``flash_bwd_dkv`` and ``flash_paged_fwd``.
 """
 from __future__ import annotations
 
@@ -313,6 +317,7 @@ def _fwd_call(q, k, v, kv_idx, kv_cnt, bq, bk, causal, window, q_offset, sk,
             jax.ShapeDtypeStruct((BH, Sqp, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(kv_idx, kv_cnt, q, k, v)
 
 
@@ -428,6 +433,7 @@ def _paged_call(q, pk, pv, kv_idx, table, ctx, bq, scale, softcap, interpret):
             jax.ShapeDtypeStruct((B, H, Sqp, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_paged_fwd",
     )(kv_idx, table, ctx, q, pk, pv)
 
 
@@ -521,6 +527,7 @@ def _dq_call(q, k, v, do, lse, delta, kv_idx, kv_cnt, bq, bk, causal, window,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BH, Sqp, d), q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(kv_idx, kv_cnt, q, k, v, do, lse, delta)
 
 
@@ -575,6 +582,7 @@ def _dkv_call(q, k, v, do, lse, delta, q_idx, q_cnt, bq, bk, causal, window,
             jax.ShapeDtypeStruct((BKV, Skp, d), v.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q_idx, q_cnt, q, k, v, do, lse, delta)
 
 

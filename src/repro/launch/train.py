@@ -29,7 +29,7 @@ from ..configs import get_config
 from ..configs.base import SparseConfig
 from ..core import TopologyTrace, mask_stats, publish_pack_gauges
 from ..core.pruning import PruningSchedule
-from ..obs import Observability, jit_retraces
+from ..obs import Observability, jit_retraces, region
 from ..checkpoint.checkpoint import Checkpointer
 from ..data import batch_for
 from ..optim import LRSchedule, OptConfig
@@ -74,7 +74,9 @@ def train_loop(
     of the observability layer (docs/observability.md): per-step train_step
     spans + a loss/gnorm counter track on the tracer, train_* gauges/
     histograms and topology-distance series in the metrics registry, and
-    kernel_* pack gauges re-published after every refresh_pack.  ``flusher``
+    kernel_* pack gauges re-published after every refresh_pack.  The step
+    spans and refresh_pack's phases are regions (obs/trace.py), so a
+    profiler trace shows them too, with or without ``obs``.  ``flusher``
     (repro.obs.PeriodicFlusher, usually ``obs.flusher(...)`` — built by
     main() from --trace-out/--metrics-out) is pumped at log cadence and
     force-flushed before return, so a live run's files stay current.
@@ -130,7 +132,8 @@ def train_loop(
             "topo": m.counter("train_topology_updates_total",
                               "drop/grow topology updates applied"),
             "step_s": m.histogram("train_step_seconds",
-                                  "host-side step dispatch time"),
+                                  "host time of a step: its dispatch and "
+                                  "the wait for its step counter"),
             "dist": m.gauge("train_topology_distance",
                             "last topology-update distance by metric",
                             labels=("metric",)),
@@ -138,55 +141,54 @@ def train_loop(
                                 "jit retraces of the train/update steps"),
         }
         publish_pack_gauges(m, state.get("pack"))
-    t0 = time.time()
+    # the ring's time axis is the regions' default clock, so the spans that
+    # refresh_pack opens nest inside the update step's
+    clock = time.perf_counter
+    t0 = clock()
     step = int(state["step"])
     while step < steps:
-        ts0 = time.time()
-        b = batch_for(cfg, step, batch, seq, learnable=learnable)
         is_update = (
             sp.method in ("rigl", "set", "snfs", "topkast")
             and step > 0
             and step % sp.delta_t == 0
             and step < algo.schedule.t_end
         )
-        if is_update:
-            prev_masks = topo_trace.snapshot(state["masks"])
-            state, m = rigl_step(state, b)
-            # topology changed: re-pack the tight-grid block topology NOW so
-            # the next delta_t train/serve steps run grids sized to the new
-            # active counts (host-side, amortized — see core/pack.py)
-            state = refresh_pack(state, cfg)
-            rec = topo_trace.record(prev_masks, state["masks"], step=step)
-            topo_log.append({"step": step, "topology": rec})
-            if om is not None:
-                om["topo"].inc()
-                for k in ("jaccard_dist", "graph_edit_dist", "nhd"):
-                    om["dist"].labels(k).set(rec[k])
-                obs.trace.instant(
-                    "topology_update", time.time() - t0, tid=0, cat="train",
-                    args={"step": step, **{k: rec[k] for k in
-                          ("dropped", "grown", "jaccard_dist", "nhd")}},
-                )
-                # the drop/grow moved blocks: re-publish the pack gauges
-                publish_pack_gauges(obs.metrics, state.get("pack"))
-        else:
-            state, m = train_step(state, b)
-        if prune_fn is not None and step % prune_sched.prune_every == 0:
-            state = prune_fn(state)
-            state = refresh_pack(state, cfg)  # pruning moved the masks too
-            if om is not None:
-                publish_pack_gauges(obs.metrics, state.get("pack"))
-        step = int(state["step"])
+        # the step as a region: a profiler trace and --trace-out both show
+        # it, from the batch to int(state["step"]), which waits for the step
+        with region("topology_update_step" if is_update else "train_step",
+                    obs=obs, cat="train") as span:
+            b = batch_for(cfg, step, batch, seq, learnable=learnable)
+            if is_update:
+                prev_masks = topo_trace.snapshot(state["masks"])
+                state, m = rigl_step(state, b)
+                # topology changed: re-pack the tight-grid block topology NOW
+                # so the next delta_t train/serve steps run grids sized to the
+                # new active counts (host-side, amortized — see core/pack.py)
+                state = refresh_pack(state, cfg, obs)
+                rec = topo_trace.record(prev_masks, state["masks"], step=step)
+                topo_log.append({"step": step, "topology": rec})
+                if om is not None:
+                    om["topo"].inc()
+                    for k in ("jaccard_dist", "graph_edit_dist", "nhd"):
+                        om["dist"].labels(k).set(rec[k])
+                    obs.trace.instant(
+                        "topology_update", clock(), tid=0, cat="train",
+                        args={"step": step, **{k: rec[k] for k in
+                              ("dropped", "grown", "jaccard_dist", "nhd")}},
+                    )
+                    # the drop/grow moved blocks: re-publish the pack gauges
+                    publish_pack_gauges(obs.metrics, state.get("pack"))
+            else:
+                state, m = train_step(state, b)
+            if prune_fn is not None and step % prune_sched.prune_every == 0:
+                state = prune_fn(state)
+                state = refresh_pack(state, cfg, obs)  # pruning moved the masks
+                if om is not None:
+                    publish_pack_gauges(obs.metrics, state.get("pack"))
+            step = int(state["step"])
+            span.args["step"] = step
         if om is not None:
-            # host-side dispatch slice (jax is async: the log-cadence block
-            # below is where queued work drains — visible as long spans
-            # there, exactly the truth of where the host waited)
-            ts1 = time.time()
-            obs.trace.span(
-                "topology_update_step" if is_update else "train_step",
-                ts0 - t0, ts1 - t0, tid=0, cat="train", args={"step": step},
-            )
-            om["step_s"].observe(ts1 - ts0)
+            om["step_s"].observe(span.seconds)
             om["steps"].inc()
         if preempt_at is not None and step == preempt_at:
             ckpt.maybe_save(state, step, force=True)
@@ -202,7 +204,7 @@ def train_loop(
             # log interval) is the pack-width-hysteresis regression signal
             rec["n_retraces"] = jit_retraces(train_step, rigl_step)
             if om is not None:
-                tnow = time.time() - t0
+                tnow = clock()
                 om["loss"].set(loss)
                 om["retraces"].set(rec["n_retraces"])
                 track = {"loss": loss}
@@ -230,12 +232,12 @@ def train_loop(
                         "without refresh_pack(); see docs/kernels.md#staleness"
                     )
             metrics_log.append(rec)
-            print(f"[train] step {step:6d} loss {loss:.4f} ({(time.time()-t0):.1f}s)")
+            print(f"[train] step {step:6d} loss {loss:.4f} ({(clock() - t0):.1f}s)")
         ckpt.maybe_save(state, step)
     ckpt.maybe_save(state, step, force=True)
     ckpt.wait()
     if flusher is not None:
-        flusher.close(time.time() - t0)
+        flusher.close(clock())
     stats = mask_stats(state["masks"])
     (workdir / "result.json").write_text(
         json.dumps({

@@ -5,9 +5,11 @@ The layer every subsystem reports through (docs/observability.md):
   * :mod:`repro.obs.metrics` — Counter/Gauge/Histogram families in a
     process-wide registry, cheap enough for host-side hot loops;
   * :mod:`repro.obs.trace` — bounded-ring span tracer emitting Chrome
-    Trace Event Format JSON (Perfetto / chrome://tracing);
-  * :mod:`repro.obs.export` — Prometheus text exposition, JSONL sink,
-    periodic flusher;
+    Trace Event Format JSON (Perfetto / chrome://tracing), and ``region``,
+    a live host span on the profiler's clock that feeds the registry's
+    span counters (and the ring, given a handle);
+  * :mod:`repro.obs.export` — Prometheus text exposition, periodic
+    flusher;
   * :mod:`repro.obs.stats_util` — empty-safe percentile/summary helpers
     shared by ``ServeEngine.stats()`` and the benches.
 
@@ -20,7 +22,7 @@ from __future__ import annotations
 
 from typing import Optional
 
-from .export import JsonlSink, PeriodicFlusher, parse_prometheus_text, prometheus_text
+from .export import PeriodicFlusher, parse_prometheus_text, prometheus_text
 from .metrics import (
     DEFAULT_BUCKETS,
     REGISTRY,
@@ -33,7 +35,7 @@ from .metrics import (
     jit_retraces,
 )
 from .stats_util import median, median_by, percentile, summarize
-from .trace import SpanTracer
+from .trace import SpanTracer, region
 
 __all__ = [
     "Observability",
@@ -47,9 +49,9 @@ __all__ = [
     "exponential_buckets",
     "jit_retraces",
     "SpanTracer",
+    "region",
     "prometheus_text",
     "parse_prometheus_text",
-    "JsonlSink",
     "PeriodicFlusher",
     "percentile",
     "median",
@@ -75,10 +77,10 @@ class Observability:
         )
 
     def flusher(self, *, metrics_path=None, trace_path=None,
-                events_path=None, interval: float = 5.0) -> PeriodicFlusher:
+                interval: float = 5.0) -> PeriodicFlusher:
         """A PeriodicFlusher wired to this bundle's registry and tracer."""
         return PeriodicFlusher(
             registry=self.metrics, tracer=self.trace,
             metrics_path=metrics_path, trace_path=trace_path,
-            events_path=events_path, interval=interval,
+            interval=interval,
         )

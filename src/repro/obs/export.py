@@ -1,4 +1,4 @@
-"""Exporters: Prometheus text exposition, JSONL event sink, periodic flusher.
+"""Exporters: Prometheus text exposition and a periodic flusher.
 
 The registry (obs/metrics.py) and tracer (obs/trace.py) accumulate in
 memory; this module is the only place telemetry touches bytes:
@@ -12,26 +12,21 @@ memory; this module is the only place telemetry touches bytes:
     the exposition is ROUND-TRIP TESTED (tests/test_obs.py): every sample
     rendered must parse back to the exact value the registry held, which
     pins the format against quoting/float-formatting rot;
-  * ``JsonlSink`` appends events (one JSON object per line) — the
-    machine-readable stream for offline analysis, complementing the
-    Perfetto trace (obs/trace.py::SpanTracer.to_chrome) meant for eyes;
   * ``PeriodicFlusher`` ties them together: call ``maybe_flush(now)`` from
-    any loop and it rewrites the metrics/trace files and appends NEW trace
-    events to the JSONL sink at most once per ``interval`` — observability
-    of a live run without a background thread (explicit clocks again, so
-    virtual-clock tests can drive flushes deterministically).
+    any loop and it rewrites the metrics and trace files at most once per
+    ``interval`` — observability of a live run without a background thread
+    (explicit clocks again, so virtual-clock tests can drive flushes
+    deterministically).
 """
 from __future__ import annotations
 
-import json
 import math
 import pathlib
-from typing import Any, Optional
+from typing import Optional
 
 __all__ = [
     "prometheus_text",
     "parse_prometheus_text",
-    "JsonlSink",
     "PeriodicFlusher",
 ]
 
@@ -142,33 +137,6 @@ def parse_prometheus_text(text: str) -> dict:
     return samples
 
 
-class JsonlSink:
-    """Append-only JSON-lines event stream (one object per line, flushed per
-    write so a crashed run keeps everything already emitted)."""
-
-    def __init__(self, path):
-        self.path = pathlib.Path(path)
-        self.path.parent.mkdir(parents=True, exist_ok=True)
-        self._f = open(self.path, "a")
-        self.n_written = 0
-
-    def write(self, obj: Any) -> None:
-        self._f.write(json.dumps(obj) + "\n")
-        self._f.flush()
-        self.n_written += 1
-
-    def close(self) -> None:
-        if self._f is not None:
-            self._f.close()
-            self._f = None
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        self.close()
-
-
 class PeriodicFlusher:
     """Rate-limited telemetry writer for live loops.
 
@@ -179,15 +147,10 @@ class PeriodicFlusher:
       metrics_path   Prometheus text file (rewritten whole each flush)
       trace_path     Chrome trace JSON (rewritten whole — the ring is the
                      retention policy, the file is a view of it)
-      events_path    JSONL sink appending only the trace events emitted
-                     since the previous flush (ring eviction cannot lose
-                     events for the sink unless more than ``capacity``
-                     events arrive within one interval — ``n_dropped``
-                     on the tracer says if that ever happened)
     """
 
     def __init__(self, *, registry=None, tracer=None, metrics_path=None,
-                 trace_path=None, events_path=None, interval: float = 5.0):
+                 trace_path=None, interval: float = 5.0):
         self.registry = registry
         self.tracer = tracer
         self.metrics_path = metrics_path
@@ -195,10 +158,8 @@ class PeriodicFlusher:
         for p in (metrics_path, trace_path):
             if p:
                 pathlib.Path(p).parent.mkdir(parents=True, exist_ok=True)
-        self.sink = JsonlSink(events_path) if events_path else None
         self.interval = interval
         self._last: Optional[float] = None
-        self._seen = 0  # tracer.n_emitted at the previous flush
         self.n_flushes = 0
 
     def maybe_flush(self, now: float, force: bool = False) -> bool:
@@ -213,20 +174,10 @@ class PeriodicFlusher:
             pathlib.Path(self.metrics_path).write_text(
                 prometheus_text(self.registry.snapshot())
             )
-        if self.tracer is not None:
-            if self.trace_path:
-                self.tracer.to_chrome(self.trace_path)
-            if self.sink is not None:
-                new = self.tracer.n_emitted - self._seen
-                if new > 0:
-                    ring = self.tracer.events
-                    for ev in list(ring)[-min(new, len(ring)):]:
-                        self.sink.write(ev)
-                self._seen = self.tracer.n_emitted
+        if self.tracer is not None and self.trace_path:
+            self.tracer.to_chrome(self.trace_path)
         self.n_flushes += 1
         return True
 
     def close(self, now: float = 0.0) -> None:
         self.maybe_flush(now, force=True)
-        if self.sink is not None:
-            self.sink.close()

@@ -33,15 +33,28 @@ table in docs/observability.md#span-taxonomy): ``ph="X"`` complete spans
 (queue_wait / prefill / decode / decode_step / train_step), ``ph="i"``
 instants (quarantine / shed / fault_injected / topology_update), ``ph="C"``
 counter tracks (loss, slot occupancy) and ``ph="M"`` metadata names.
+
+``region`` times a block of host code live: it is a
+``jax.profiler.TraceAnnotation`` (so the span lands in a profiler trace on
+the device ops' clock), adds its seconds to the registry's span counters,
+and, given an ``Observability`` handle, emits the same span into the ring.
 """
 from __future__ import annotations
 
 import json
 import pathlib
+import threading
+import time
 from collections import deque
 from typing import Any, Optional
 
-__all__ = ["SpanTracer"]
+import jax
+
+from .metrics import REGISTRY
+
+__all__ = ["SpanTracer", "region"]
+
+_SPAN_LABEL = ("span",)  # the label of the registry families regions feed
 
 
 def _us(t: float) -> int:
@@ -154,3 +167,76 @@ class SpanTracer:
         cross-checking emitted annotations against ground truth (e.g.
         quarantine instants vs FaultInjector.log)."""
         return [e for e in self.events if e.get("name") == name]
+
+
+_open = threading.local()  # per thread: names of the regions entered
+
+
+class region:
+    """Context manager timing one block of host code as a named span.
+
+    ``with region("repro.refresh_pack", obs=obs): ...``
+
+    - enters ``jax.profiler.TraceAnnotation(name, **args)``: while the
+      profiler records, the span lands in its host plane on the same clock
+      as the device ops; otherwise it costs one ``TraceMe`` and nothing is
+      formatted;
+    - on exit adds the span's seconds and one count to
+      ``repro_span_seconds_total{span}`` / ``repro_spans_total{span}`` and
+      sets ``repro_span_last_seconds{span}``, in ``obs.metrics`` when a
+      handle is given, else the process-wide ``REGISTRY``;
+    - only with a handle, emits the ring span (``ph="X"``) from the same two
+      clock readings, with the enclosing region's name in
+      ``args["parent"]``.
+
+    The clock is the caller's (``time.perf_counter`` by default), so
+    virtual-clock runs stay bit-identical; the profiler keeps its own.  The
+    block may add to ``.args`` before it ends (the ring span carries them);
+    ``.seconds`` holds the span's length after exit.  A region adds no
+    synchronisation: work the block dispatches asynchronously is timed as
+    the host time it took to dispatch.
+    """
+
+    __slots__ = ("name", "obs", "clock", "cat", "args", "parent", "t0",
+                 "seconds", "_annotation")
+
+    def __init__(self, name: str, *, obs=None, clock=time.perf_counter,
+                 cat: str = "", **args):
+        self.name = name
+        self.obs = obs
+        self.clock = clock
+        self.cat = cat
+        self.args = args
+        self.seconds = 0.0
+
+    def __enter__(self) -> "region":
+        stack = getattr(_open, "names", None)
+        if stack is None:
+            stack = _open.names = []
+        self.parent = stack[-1] if stack else None
+        stack.append(self.name)
+        self._annotation = jax.profiler.TraceAnnotation(self.name, **self.args)
+        self._annotation.__enter__()
+        self.t0 = self.clock()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        t1 = self.clock()
+        self._annotation.__exit__(*exc)
+        _open.names.pop()
+        # a wall clock can step back: a span is never negative
+        self.seconds = max(t1 - self.t0, 0.0)
+        obs = self.obs
+        reg = obs.metrics if obs is not None else REGISTRY
+        reg.counter("repro_span_seconds_total", "seconds spent inside each "
+                    "region", _SPAN_LABEL).labels(self.name).inc(self.seconds)
+        reg.counter("repro_spans_total", "regions completed",
+                    _SPAN_LABEL).labels(self.name).inc()
+        reg.gauge("repro_span_last_seconds", "seconds of the latest completed "
+                  "region", _SPAN_LABEL).labels(self.name).set(self.seconds)
+        if obs is not None:
+            args = self.args
+            if self.parent is not None:
+                args = dict(args, parent=self.parent)
+            obs.trace.span(self.name, self.t0, t1, cat=self.cat, args=args)
+        return False

@@ -59,6 +59,7 @@ from ..core import (
 )
 from ..core.pruning import PruningSchedule, prune_step
 from ..models import init_lm, lm_loss
+from ..obs import region
 from ..optim import (
     LRSchedule,
     OptConfig,
@@ -233,7 +234,7 @@ def init_train_state(key, cfg, opt_cfg: OptConfig, *, loss_fn=None):
     return state, axes, sparse_flags
 
 
-def refresh_superset(state, cfg):
+def refresh_superset(state, cfg, obs=None):
     """Redraw the Top-KAST backward supersets from the CURRENT masks/params.
 
     Called from refresh_pack right after every topology update.  For
@@ -246,15 +247,23 @@ def refresh_superset(state, cfg):
     buffer is masked to the new superset either way: coordinates without a
     gradient channel must not carry stale momentum into grow scores.
     No-op for states without backward masks.
+
+    Regions (obs/trace.py): ``repro.refresh_pack.drain`` is the host reading
+    the step counter, which waits for the step that produced the state (the
+    update step); ``repro.refresh_pack.superset`` dispatches the redraw,
+    which runs on the device while the host goes on.
     """
     if "bwd_masks" not in state:
         return state
     sp = cfg.sparse
-    key = jax.random.fold_in(state["rng"], 2 ** 20 + int(state["step"]))
-    new_b = topkast_backward_masks(
-        state["params"], state["masks"], sp.backward_extra, key,
-        block_shape=sp.block_shape,
-    )
+    with region("repro.refresh_pack.drain", obs=obs):
+        step = int(state["step"])
+    with region("repro.refresh_pack.superset", obs=obs):
+        key = jax.random.fold_in(state["rng"], 2 ** 20 + step)
+        new_b = topkast_backward_masks(
+            state["params"], state["masks"], sp.backward_extra, key,
+            block_shape=sp.block_shape,
+        )
     new_state = dict(state, bwd_masks=new_b)
     if sp.method == "topkast":
         leavers = jax.tree_util.tree_map(
@@ -280,7 +289,7 @@ def refresh_superset(state, cfg):
     return new_state
 
 
-def refresh_pack(state, cfg):
+def refresh_pack(state, cfg, obs=None):
     """Refresh superset + re-pack state["pack"] from state["masks"].
 
     Call right after EVERY topology-update step (host-side, amortized over
@@ -294,22 +303,30 @@ def refresh_pack(state, cfg):
     ``cfg.sparse.pack_width_slack`` > 0 additionally rounds refreshed widths
     up to the next slack step (core.pack.slack_width), trading a few padded
     grid iterations for fewer retraces when production topologies drift.
+
+    The whole refresh is the region ``repro.refresh_pack``, the parent of
+    refresh_superset's and the pack's phase regions and of
+    ``repro.pack.validate`` (docs/observability.md#span-taxonomy); ``obs``
+    is an optional Observability handle that also puts them in its ring.
     """
-    state = refresh_superset(state, cfg)
-    if "pack" not in state:
-        return state
-    if cfg.sparse.kernel == "masked":
-        return dict(state, pack=build_bwd_carrier(state["bwd_masks"]))
-    pack = refresh_pack_state(
-        state["masks"], cfg.sparse.block_shape, prev=state["pack"],
-        slack=getattr(cfg.sparse, "pack_width_slack", 0.0),
-        bwd_masks=state.get("bwd_masks"),
-    )
-    # integrity guard (core/pack.py::validate_pack): a refresh that produced
-    # inconsistent CSC/CSR books would make every subsequent kernel launch
-    # execute the wrong topology — cheap host-side check, loud failure
-    validate_pack(pack, where="refresh_pack")
-    return dict(state, pack=pack)
+    with region("repro.refresh_pack", obs=obs):
+        state = refresh_superset(state, cfg, obs)
+        if "pack" not in state:
+            return state
+        if cfg.sparse.kernel == "masked":
+            return dict(state, pack=build_bwd_carrier(state["bwd_masks"]))
+        pack = refresh_pack_state(
+            state["masks"], cfg.sparse.block_shape, prev=state["pack"],
+            slack=getattr(cfg.sparse, "pack_width_slack", 0.0),
+            bwd_masks=state.get("bwd_masks"), obs=obs,
+        )
+        # integrity guard (core/pack.py::validate_pack): a refresh that
+        # produced inconsistent CSC/CSR books would make every subsequent
+        # kernel launch execute the wrong topology — cheap host-side check,
+        # loud failure
+        with region("repro.pack.validate", obs=obs):
+            validate_pack(pack, where="refresh_pack")
+        return dict(state, pack=pack)
 
 
 def make_train_step(

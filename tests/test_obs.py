@@ -13,7 +13,10 @@ What this tier pins (docs/observability.md):
     BIT-IDENTICAL metric snapshots and trace events across two runs
     (metrics as regression oracle, not just dashboard feed), quarantine
     instants mirror both ``engine.quarantine_log`` and the FaultInjector's
-    fired log, and instrumentation never perturbs token streams.
+    fired log, and instrumentation never perturbs token streams;
+  * regions — ``region`` spans under a virtual clock are bit-identical
+    across runs, nest by parent, and feed the span counters;
+    ``refresh_pack`` records each of its phases and the bytes it fetches.
 """
 import dataclasses
 import json
@@ -23,12 +26,13 @@ import jax
 import pytest
 
 from repro.configs import get_config
+from repro.configs.base import SparseConfig
 from repro.models import init_lm
 from repro.obs import (
+    REGISTRY,
     Counter,
     Gauge,
     Histogram,
-    JsonlSink,
     MetricsRegistry,
     Observability,
     PeriodicFlusher,
@@ -39,9 +43,12 @@ from repro.obs import (
     parse_prometheus_text,
     percentile,
     prometheus_text,
+    region,
     summarize,
 )
+from repro.optim import OptConfig
 from repro.serving import FaultInjector, ServeEngine, Status, burst_storm
+from repro.training import init_train_state, refresh_pack
 
 pytestmark = pytest.mark.obs
 
@@ -221,31 +228,128 @@ def test_periodic_flusher_rate_limit_and_incremental_sink(tmp_path):
     fl = PeriodicFlusher(
         registry=reg, tracer=tr,
         metrics_path=tmp_path / "m.prom", trace_path=tmp_path / "t.json",
-        events_path=tmp_path / "e.jsonl", interval=5.0,
+        interval=5.0,
     )
     assert fl.maybe_flush(0.0) is True
     assert fl.maybe_flush(3.0) is False  # inside the interval: rate-limited
     tr.instant("b", 4.0)
+    reg.counter("x_total").inc()
     assert fl.maybe_flush(6.0) is True
     fl.close(now=6.0)
+    assert fl.n_flushes == 3
 
-    # sink got each event exactly once (incremental via n_emitted deltas)
-    lines = (tmp_path / "e.jsonl").read_text().splitlines()
-    assert [json.loads(l)["name"] for l in lines] == ["a0", "a1", "a2", "b"]
     parsed = parse_prometheus_text((tmp_path / "m.prom").read_text())
-    assert parsed["x_total"][frozenset()] == 1
-    assert json.loads((tmp_path / "t.json").read_text())["traceEvents"]
+    assert parsed["x_total"][frozenset()] == 2
+    events = json.loads((tmp_path / "t.json").read_text())["traceEvents"]
+    assert [e["name"] for e in events] == ["a0", "a1", "a2", "b"]
 
 
-def test_jsonl_sink_appends(tmp_path):
-    p = tmp_path / "nested" / "events.jsonl"  # parents created
-    with JsonlSink(p) as s:
-        s.write({"a": 1})
-    with JsonlSink(p) as s:  # reopen appends, never truncates
-        s.write({"b": 2})
-    assert [json.loads(l) for l in p.read_text().splitlines()] == [
-        {"a": 1}, {"b": 2}
+# ---------------------------------------------------------------------------
+# regions: live spans on the profiler's clock, fed to counters and the ring
+# ---------------------------------------------------------------------------
+
+
+def _ticks(*ts):
+    it = iter(ts)
+    return lambda: next(it)
+
+
+def _region_run():
+    obs = Observability(metrics=MetricsRegistry(), pid=1, process_name="train")
+    # reads: outer enter, inner enter/exit, inner enter/exit, outer exit
+    clock = _ticks(10.0, 10.5, 11.25, 12.0, 13.5, 14.0)
+    with region("repro.outer", obs=obs, clock=clock, cat="train",
+                step=3) as outer:
+        for _ in range(2):
+            with region("repro.outer.inner", obs=obs, clock=clock):
+                pass
+        outer.args["done"] = True
+    return obs, outer
+
+
+def test_region_virtual_clock_bit_identical():
+    obs1, outer = _region_run()
+    obs2, _ = _region_run()
+    assert obs1.metrics.snapshot() == obs2.metrics.snapshot()
+    assert obs1.trace.chrome_events() == obs2.trace.chrome_events()
+
+    assert outer.seconds == 4.0
+    inner, last = obs1.trace.find("repro.outer.inner"), obs1.trace.find(
+        "repro.outer")
+    assert [(e["ts"], e["dur"], e["args"]) for e in inner] == [
+        (10_500_000, 750_000, {"parent": "repro.outer"}),
+        (12_000_000, 1_500_000, {"parent": "repro.outer"}),
     ]
+    assert last == [{"ph": "X", "name": "repro.outer", "cat": "train",
+                     "pid": 1, "tid": 0, "ts": 10_000_000,
+                     "dur": 4_000_000, "args": {"step": 3, "done": True}}]
+    m = obs1.metrics
+    assert m.get("repro_span_seconds_total").labels(
+        "repro.outer.inner").value == 2.25
+    assert m.get("repro_spans_total").labels("repro.outer.inner").value == 2
+    assert m.get("repro_span_last_seconds").labels(
+        "repro.outer.inner").value == 1.5
+    assert m.get("repro_span_seconds_total").labels("repro.outer").value == 4.0
+
+
+def test_region_without_handle_counts_in_process_registry():
+    spans = REGISTRY.counter("repro_spans_total", "", ("span",))
+    before = spans.labels("repro.test.bare").value
+    with pytest.raises(KeyError):
+        with region("repro.test.bare", clock=_ticks(1.0, 3.0)):
+            raise KeyError("the region ends and the error goes on")
+    assert spans.labels("repro.test.bare").value == before + 1
+    assert REGISTRY.get("repro_span_last_seconds").labels(
+        "repro.test.bare").value == 2.0
+    # a clock that steps back makes a zero-length span, not a negative one
+    with region("repro.test.bare", clock=_ticks(5.0, 4.0)) as r:
+        pass
+    assert r.seconds == 0.0
+
+
+def test_refresh_pack_records_each_phase_and_bytes():
+    cfg = get_config("h2o-danube-1.8b", smoke=True)
+    sp = SparseConfig(
+        sparsity=0.8, method="rigl", delta_t=10, alpha=0.3,
+        kernel="block_sparse", block_shape=(16, 16),
+        kernel_block=(128, 16, 16),
+    )
+    cfg = dataclasses.replace(cfg, dtype="float32", sparse=sp)
+    st, _, _ = init_train_state(jax.random.PRNGKey(0), cfg,
+                                OptConfig(kind="adam"))
+    obs = Observability(metrics=MetricsRegistry())
+    st = refresh_pack(st, cfg, obs)
+
+    packed = [e for e in jax.tree_util.tree_leaves(
+        st["pack"], is_leaf=lambda x: x is None or "idx" in x) if e is not None]
+    leaves = jax.tree_util.tree_leaves
+    counts = {e["name"]: 0 for e in obs.trace.events}
+    for e in obs.trace.events:
+        counts[e["name"]] += 1
+    assert counts == {
+        "repro.refresh_pack.drain": 1, "repro.refresh_pack.superset": 1,
+        "repro.pack.to_host": len(packed), "repro.pack.build": len(packed),
+        "repro.pack.to_device": len(packed), "repro.pack.validate": 1,
+        "repro.refresh_pack": 1,
+    }
+    parents = {e["name"]: e.get("args", {}).get("parent")
+               for e in obs.trace.events}
+    assert parents.pop("repro.refresh_pack") is None
+    assert set(parents.values()) == {"repro.refresh_pack"}
+    # every packed leaf's mask and superset were fetched, and counted
+    fetched = obs.metrics.get("repro_pack_bytes_to_host_total")._default()
+    assert fetched.value == sum(
+        x.nbytes for x in leaves(st["masks"]) + leaves(st["bwd_masks"]))
+    # the phases do not overlap and lie inside the refresh: their (self)
+    # times sum to no more than the parent's
+    secs = {k: c.value for (k,), c in
+            obs.metrics.get("repro_span_seconds_total").series()}
+    parent = secs.pop("repro.refresh_pack")
+    assert 0 < sum(secs.values()) <= parent
+    top = obs.trace.find("repro.refresh_pack")[0]
+    for e in obs.trace.events:
+        assert top["ts"] <= e["ts"] and e["ts"] + e["dur"] <= (
+            top["ts"] + top["dur"] + 1)  # µs rounding
 
 
 # ---------------------------------------------------------------------------
