@@ -71,11 +71,22 @@ class SparseConfig:
                                       active-block count (tight grids).
                        Both Pallas paths carry custom-VJP backward kernels
                        (kernels/masked_matmul.py, block_sparse_matmul.py).
-      kernel_block     (bm, bn, bk) Pallas tile sizes: bm rows of the
-                       flattened batch*seq dim, bn output columns, bk
-                       contraction rows.  128-aligned tiles target TPU v5e;
-                       for kernel='block_sparse', (bk, bn) doubles as the
-                       weight-block granularity and must match block_shape.
+      kernel_block     (bm, bn, bk) Pallas tile sizes: bn output columns,
+                       bk contraction rows, and bm the row-tile GRANULE of
+                       the flattened batch*seq dim.  The row tile is sized
+                       from the rows each call sees (kernels/ops.py::
+                       _row_tile): rows below one granule pad to 16; more
+                       pad to whole granules, and the tile is the largest
+                       whole number of granules that divides them within
+                       the VMEM budget of 2048 rows (1.5 KiB a row for
+                       fwd/dgrad at 128 x 128 in bf16: double-buffered
+                       input and output rows plus an f32 accumulator row,
+                       3 MiB in all).  A 2048-token microbatch is one row
+                       tile; the choice is published as the gauge
+                       kernel_row_tile{rows}.  128-aligned tiles target TPU
+                       v5e; for kernel='block_sparse', (bk, bn) doubles as
+                       the weight-block granularity and must match
+                       block_shape.
       pack_width_slack width hysteresis for PackState refreshes (core/pack.py):
                        packed widths are rounded UP to the next multiple of
                        ``ceil(slack * worst_case_width)`` (and never shrink),

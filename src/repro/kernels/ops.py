@@ -10,7 +10,9 @@ Both linear wrappers are fully differentiable (the underlying kernels carry
 custom-VJP Pallas backward passes) and accept NON-ALIGNED leading dims: the
 flattened batch*seq rows are zero-padded up to the M tile and trimmed after,
 so odd shapes (e.g. decode with batch 4, or batch*seq not a 128 multiple)
-dispatch without caller-side padding.  ``masked_linear`` additionally pads
+dispatch without caller-side padding.  The row tile is sized from the rows
+(``_row_tile``): whole ``kernel_block[0]`` granules up to the VMEM budget, so
+a 2048-token microbatch is one row tile.  ``masked_linear`` additionally pads
 K/N when they don't divide the tile; ``block_sparse_linear`` requires aligned
 K/N because the block mask's grid is defined by them.
 
@@ -35,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.metrics import REGISTRY
 from .block_sparse_matmul import (
     block_sparse_matmul,
     fused_block_sparse_matmul,
@@ -96,12 +99,39 @@ def _round_up(n: int, mult: int) -> int:
     return ((n + mult - 1) // mult) * mult
 
 
+# Largest row tile, in rows: the kernels' VMEM budget.  Per row of tile, at
+# 128 x 128 blocks in bf16, fwd and dgrad hold a double-buffered input row
+# (2 x bk x 2 B), a double-buffered output row (2 x bn x 2 B) and an f32
+# accumulator row (bn x 4 B): 1.5 KiB; wgrad holds two double-buffered input
+# rows: 1 KiB.  2048 rows take 3 MiB (5 MiB with f32 operands), well inside
+# the v5e's 16 MiB default scoped VMEM, and a 2048-token microbatch runs as
+# one row tile.
+_MAX_ROW_TILE = 2048
+
+
 def _row_tile(M: int, bm: int) -> tuple[int, int]:
-    """(effective row tile, padded M).  Rows below one tile shrink the tile to
-    the 16-padded row count (16 = bf16 sublane min) instead of padding a tiny
-    batch all the way to bm."""
-    bm_eff = min(bm, _round_up(M, 16))
-    return bm_eff, _round_up(M, bm_eff)
+    """(row tile, padded M) for M rows; bm (``kernel_block[0]``) is the
+    granule the tile grows by.
+
+    Rows below one granule shrink the tile to the 16-padded row count (16 =
+    bf16 sublane min) instead of padding a tiny batch all the way to bm.
+    Otherwise M pads to whole granules, and the tile is the largest whole
+    number of granules that divides the padded M and stays within
+    ``_MAX_ROW_TILE``: the whole padded M where it fits.  Every grid step of
+    the kernels then covers as many rows as VMEM allows, and the padding is
+    never more than one granule's.  The choice is published as the gauge
+    ``kernel_row_tile{rows}`` (rows = padded M) while the call is traced.
+    """
+    tile = min(bm, _round_up(M, 16))
+    Mp = _round_up(M, tile)
+    n = Mp // tile
+    most = max(_MAX_ROW_TILE // tile, 1)
+    tile *= max(d for d in range(1, min(n, most) + 1) if n % d == 0)
+    REGISTRY.gauge(
+        "kernel_row_tile", "row tile of the block-sparse and masked kernels",
+        labels=("rows",),
+    ).labels(Mp).set(tile)
+    return tile, Mp
 
 
 def _pad_rows(x2, Mp: int):

@@ -8,7 +8,14 @@ import pytest
 from _hyp import given, settings, st
 
 from repro.kernels import ref
-from repro.kernels.ops import block_sparse_linear, masked_linear, topk_threshold
+from repro.kernels.ops import (
+    _MAX_ROW_TILE,
+    _row_tile,
+    block_sparse_linear,
+    grouped_block_sparse_linear,
+    masked_linear,
+    topk_threshold,
+)
 
 pytestmark = pytest.mark.kernels
 
@@ -121,6 +128,81 @@ def test_block_sparse_grad_vs_ref(density):
     )
     # packed wgrad scatters ONLY active blocks; everything else exactly zero
     assert float(jnp.max(jnp.abs(jnp.where(dense_mask, 0.0, gw_k)))) == 0.0
+
+
+@pytest.mark.parametrize("M", [8, 100, 128, 129, 512, 2048, 3000, 8192])
+def test_row_tile(M):
+    """The row tile grows by whole granules up to the VMEM budget, and pads
+    no more rows than a fixed granule-sized tile would."""
+    granule = 128
+    tile, Mp = _row_tile(M, granule)
+    fixed = min(granule, -(-M // 16) * 16)
+    assert Mp == -(-M // fixed) * fixed  # the fixed tile's padding, no more
+    assert Mp % tile == 0 and tile <= max(_MAX_ROW_TILE, fixed)
+    if M >= granule:
+        assert tile % granule == 0
+    expect = {8: 16, 100: 112, 512: 512, 2048: 2048, 3000: 1536,
+              8192: _MAX_ROW_TILE}
+    if M in expect:
+        assert tile == expect[M]
+
+
+@pytest.mark.parametrize("tile", [512, 128])
+@pytest.mark.parametrize("path", ["plain", "topkast", "grouped"])
+def test_block_sparse_row_tiles_vs_ref(monkeypatch, path, tile):
+    """512 rows at a 128-row granule run as one 512-row tile, or, under a
+    128-row budget, as four; forward and jax.grad (dgrad, and wgrad reduced
+    over the row tiles) match the oracle either way."""
+    from repro.core.pack import pack_entry
+    from repro.kernels import ops
+
+    M, K, N, bs = 512, 256, 192, 64
+    monkeypatch.setattr(ops, "_MAX_ROW_TILE", tile)
+    assert _row_tile(M, 128) == (tile, M)
+    key = jax.random.PRNGKey(29)
+    lead = (2,) if path == "grouped" else ()
+    # activations at the scale a normalized layer feeds (1/sqrt(K)), so f32
+    # rounding over 512 rows stays below the tolerances whatever the tile
+    x = jax.random.normal(key, (*lead, M, K), jnp.float32) / np.sqrt(K)
+    w = jax.random.normal(jax.random.fold_in(key, 1), (*lead, K, N), jnp.float32)
+    blocks = jax.random.uniform(
+        jax.random.fold_in(key, 2), (*lead, K // bs, N // bs)) < 0.5
+    grow = jax.random.uniform(
+        jax.random.fold_in(key, 3), (*lead, K // bs, N // bs)) < 0.3
+    expand = lambda b: jnp.repeat(jnp.repeat(b, bs, axis=-2), bs, axis=-1)
+    block = (128, bs, bs)
+    if path == "grouped":
+        kern = lambda x, w: grouped_block_sparse_linear(
+            x, w, blocks, block=block, interpret=True)
+        oracle = lambda x, w: ref.grouped_block_sparse_matmul_ref(
+            x, w, blocks, bs, bs)
+        wgrad_mask = expand(blocks)
+        tol = dict(rtol=1e-5, atol=1e-5)
+    else:
+        pack = pack_entry(  # Top-KAST: wgrad runs on the superset's blocks
+            np.asarray(expand(blocks)), (bs, bs),
+            bwd_mask=np.asarray(expand(blocks | grow))
+            if path == "topkast" else None,
+        )
+        assert ("bidx" in pack) == (path == "topkast")
+        kern = lambda x, w: block_sparse_linear(
+            x, w, pack=pack, block=block, interpret=True)
+        oracle = lambda x, w: ref.block_sparse_matmul_ref(x, w, blocks, bs, bs)
+        wgrad_mask = expand(blocks | grow if path == "topkast" else blocks)
+        tol = dict(rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(
+        np.asarray(kern(x, w)), np.asarray(oracle(x, w)), atol=1e-3
+    )
+    loss = lambda f: lambda x, w: jnp.sum(jnp.cos(f(x, w)))
+    gx_k, gw_k = jax.grad(loss(kern), argnums=(0, 1))(x, w)
+    gx_r = jax.grad(loss(oracle))(x, w)
+    # wgrad is the dense gradient at the forward's weights on its own blocks
+    w_fwd = w * expand(blocks).astype(w.dtype)
+    dense = lambda we: jnp.sum(jnp.cos(jnp.einsum("...mk,...kn->...mn", x, we)))
+    gw_r = jax.grad(dense)(w_fwd) * wgrad_mask.astype(w.dtype)
+    np.testing.assert_allclose(np.asarray(gx_k), np.asarray(gx_r), **tol)
+    np.testing.assert_allclose(np.asarray(gw_k), np.asarray(gw_r), **tol)
+    assert float(jnp.max(jnp.abs(jnp.where(wgrad_mask, 0.0, gw_k)))) == 0.0
 
 
 def test_block_sparse_grad_traced_mask_under_jit():

@@ -24,6 +24,7 @@ from repro.kernels import (
     masked_linear,
 )
 from repro.kernels.flash_attention import flash_attention, flash_attention_paged
+from repro.obs import REGISTRY
 
 pytestmark = pytest.mark.kernels
 
@@ -69,12 +70,20 @@ def _compiled_text(fn, *args):
     return text
 
 
-def _pack(density=0.2, seed=0):
-    """PackState entry of a random 128-block topology over (D, F)."""
+def _mask(density=0.2, seed=0):
+    """Random 128-block topology over (D, F), as an element mask."""
     rng = np.random.default_rng(seed)
     blocks = rng.random((D // BLOCK, F // BLOCK)) < density
-    mask = np.kron(blocks, np.ones((BLOCK, BLOCK), bool))
-    return pack_entry(mask, (BLOCK, BLOCK))
+    return np.kron(blocks, np.ones((BLOCK, BLOCK), bool))
+
+
+def _pack(**kw):
+    """PackState entry of ``_mask()``."""
+    return pack_entry(_mask(), (BLOCK, BLOCK), **kw)
+
+
+def _row_tile_gauge(rows):
+    return REGISTRY.get("kernel_row_tile").labels(rows).value
 
 
 def _fwd_and_vjp(linear_fn):
@@ -111,6 +120,29 @@ def test_block_sparse_linear_fwd_vjp_compiles(one_chip):
     )
 
 
+@pytest.mark.parametrize("rows,tile", [(2048, 2048), (8192, 2048)])
+def test_block_sparse_worst_case_widths_fwd_vjp_compiles(one_chip, rows, tile):
+    """A training microbatch (2048 rows) and RigL's update step over the
+    whole batch (8192 rows), at worst-case packed widths (slack 1.0) with the
+    Top-KAST superset driving wgrad: the row tile grows to the VMEM budget."""
+    pack = _pack(slack=1.0, bwd_mask=_mask() | _mask(0.1, seed=1))
+    assert pack["idx"].shape[1] == D // BLOCK  # worst-case widths
+    assert pack["ridx"].shape[1] == F // BLOCK
+    assert pack["bidx"].shape[1] == D // BLOCK
+    text = _compiled_text(
+        _fwd_and_vjp(
+            lambda x, w, pk: block_sparse_linear(
+                x, w, block=(128, BLOCK, BLOCK), pack=pk, interpret=False
+            )
+        ),
+        _spec((rows, D), jnp.bfloat16, one_chip),
+        _spec((D, F), jnp.bfloat16, one_chip),
+        _abstract(pack, one_chip),
+    )
+    assert text.count("tpu_custom_call") >= 3  # fwd, dx, dw
+    assert _row_tile_gauge(rows) == tile
+
+
 def test_block_sparse_decode_rows_compile(one_chip):
     """M=8: one decode step of an 8-slot engine (rows pad to a 16-row tile)."""
     pack = _abstract(_pack(), one_chip)
@@ -122,6 +154,7 @@ def test_block_sparse_decode_rows_compile(one_chip):
         _spec((D, F), jnp.bfloat16, one_chip),
         pack,
     )
+    assert _row_tile_gauge(16) == 16
 
 
 def test_flash_tight_fwd_vjp_compiles(one_chip):
