@@ -5,6 +5,39 @@ metrics; a cell's file is ``chipbench/workloads/<cell>.json``, its
 configuration ``chipbench/configs/<config>.json``, its traffic generator
 ``chipbench/traffic/<kind>.py`` and its driver ``chipbench/<driver>.py``;
 a per-layer metric is read by ``chipbench/metrics/<name>.py``.
+
+A configuration names its plain reference, ``chipbench/reference/<name>.py``
+(``reference``), which imports nothing of the program.  ``m`` below is the
+configuration's ``model``, ``block`` and ``sparsity`` its ``sparse``
+settings.  A training cell and the work counts (``chipbench/work.py``) take
+from it:
+
+- ``make_weights(key, mask_key, m, sparsity, block) -> (params, masks)``,
+  traced under ``jax.jit`` with all but the two raw keys static: float32
+  weights from ``key`` in the program's parameter layout, and a mask tree
+  of the same structure whose leaf is ``None`` for a dense weight and, for
+  a sparse one, a bool array of its shape made of whole ``block`` x
+  ``block`` blocks of its last two dims (any leading dims are the groups of
+  a bank, each with its own blocks), drawn from ``mask_key`` with
+  ``erk_blocks``' counts in every layer; weights off the mask are zero;
+- ``loss(params, masks, m, tokens, targets, precision) -> float32 scalar``:
+  the mean next-token cross-entropy of (B, S) int32 ``tokens`` against
+  ``targets``, with the weights as ``w * mask``; ``precision`` is ``"f32"``
+  (float32 at ``highest``) or the control's lower one (``"fp8"``);
+- ``layer_shapes(m) -> {matrix: (k, n) or (groups, k, n)}``: the sparse
+  matrices of one layer, the same in every layer;
+- ``erk_blocks(m, sparsity, block) -> {matrix: int}``: active blocks of
+  each, summed over a bank's groups;
+- optional ``rows_per_token(m) -> {matrix: number}``: for a matrix whose
+  products do not see every token, the rows each group's product sees per
+  token (a bank of experts with k of E routed per token: k / E); a matrix
+  it leaves out sees every token once;
+- optional ``attn_windows(m) -> [int]``: the window of each attention
+  layer, 0 for the whole causal context; without it, ``m["window"]`` in
+  each of ``m["n_layers"]`` layers.
+
+The serving driver also runs the reference layer by layer (``_layer``,
+``_rmsnorm``, ``logits``; ``chipbench/serve.py::reference_gaps``).
 """
 from __future__ import annotations
 

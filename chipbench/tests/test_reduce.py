@@ -1,9 +1,10 @@
 """The trace reduction and the work counts, against hand counts."""
 import pathlib
+import types
 
 import pytest
 
-from chipbench import reduce, work
+from chipbench import harness, reduce, work
 
 DATA = pathlib.Path(__file__).parent / "data"
 
@@ -67,7 +68,8 @@ def test_breakdown_top_ops_and_gaps_by_host_span():
 
 # -- work counts --------------------------------------------------------
 
-TOY = {"model": {"n_layers": 1, "d_model": 256, "n_heads": 2, "n_kv_heads": 1,
+TOY = {"reference": "danube",
+       "model": {"n_layers": 1, "d_model": 256, "n_heads": 2, "n_kv_heads": 1,
                  "head_dim": 128, "d_ff": 256, "vocab_size": 512,
                  "window": 0},
        "sparse": {"sparsity": 0.0, "block": 128}}
@@ -125,6 +127,98 @@ def test_training_counts_active_weights_not_dense():
     assert kept == pytest.approx(0.2, abs=0.02)
     f, b = work.bsmm_train(sparse, 100)
     assert f == 6 * 100 * work.sparse_weights(sparse)
+
+
+# Danube's counts as they were when work.py still read danube's shapes
+# directly: reading them through the configuration's reference keeps them.
+DANUBE = {
+    "danube-1.8b-train": {
+        ("sparse_weights",): 55443456,
+        ("train_flops_per_token", 2048): 950071296.0,
+        ("train_flops_per_token", 8192): 1201698816.0,
+        ("bsmm_train", 8192): (2725156749312, 9301917696),
+        ("bsmm_decode", 1): (110886912, 111247360),
+        ("bsmm_decode", 16): (1774190592, 116654080),
+        ("prefill", 1020): (134596771840, 285171712),
+        ("prefill", 5000): (1049945702400, 325926912),
+        ("flash_prefill", 1020): (24159191040, 52224000),
+        ("flash_prefill", 5000): (531502202880, 256000000),
+        ("decode_step", ((1020, 10), (3000, 2000))): (759414784, 327217152),
+    },
+    "danube-1.8b-serve": {
+        ("sparse_weights",): 332660736,
+        ("train_flops_per_token", 2048): 3242827776.0,
+        ("train_flops_per_token", 8192): 4752592896.0,
+        ("bsmm_train", 8192): (16350940495872, 55811506176),
+        ("bsmm_decode", 1): (665321472, 667484160),
+        ("bsmm_decode", 16): (10645143552, 699924480),
+        ("prefill", 1020): (806761431040, 891830272),
+        ("prefill", 5000): (6298855014400, 1136361472),
+        ("flash_prefill", 1020): (144955146240, 313344000),
+        ("flash_prefill", 5000): (3189013217280, 1536000000),
+        ("decode_step", ((1020, 10), (3000, 2000))): (2918088704, 1144102912),
+    },
+}
+
+
+@pytest.mark.parametrize("config,call,want", [
+    (c, call, want) for c, calls in DANUBE.items()
+    for call, want in calls.items()])
+def test_danube_counts_are_pinned(config, call, want):
+    conf = harness.load_json(harness.HERE / "configs" / f"{config}.json")
+    name, *args = call
+    assert getattr(work, name)(conf, *args) == want
+
+
+# One attention matrix and a bank of 4 experts, 2 of them routed per token;
+# one layer attends over the whole causal context, the other over 128 keys.
+STUB = types.SimpleNamespace(
+    layer_shapes=lambda m: {"attn": (256, 256), "experts": (4, 256, 512)},
+    erk_blocks=lambda m, sparsity, block: {"attn": 2, "experts": 6},
+    rows_per_token=lambda m: {"experts": 0.5},
+    attn_windows=lambda m: [0, 128],
+)
+STUB_CONF = {"reference": "stub", "sparse": {"sparsity": 0.5, "block": 128},
+             "model": dict(TOY["model"], n_layers=2)}
+
+
+def test_grouped_counts_by_hand(monkeypatch):
+    monkeypatch.setattr(harness, "reference", lambda conf: STUB)
+    c, B = STUB_CONF, 128 * 128
+    attn_w, bank_w = 2 * B, 6 * B  # active weights of one layer
+    assert work.sparse_weights(c) == 2 * (attn_w + bank_w)
+    # a token passes the attention matrix once and half of the bank's
+    # weights (2 of 4 experts)
+    T = 2 * (attn_w + bank_w / 2)
+    Hd = 256 * 512
+    assert work.token_weights(c) == T
+    # attention: causal over 256 keys, and 128 keys past the window
+    keys = 256 * 257 // 2 + (128 * 129 // 2 + 128 * 128)
+    assert work.train_flops_per_token(c, 256) == 6 * (T + Hd) + (
+        3 * 4 * 2 * 128 * keys / 256)
+    # 100 tokens: the attention matrix's products over 100 rows, each
+    # expert's over 50, 200 rows in all through the bank
+    f, b = work.bsmm_train(c, 100)
+    assert f == 2 * 3 * 2 * (100 * attn_w + 50 * bank_w)
+    attn_b = 2 * (100 * 256 * 2 + attn_w * 2 + 100 * 256 * 2) + (
+        100 * 512 * 2 + attn_w * 4)
+    bank_b = 2 * (200 * 256 * 2 + bank_w * 2 + 200 * 512 * 2) + (
+        200 * 768 * 2 + bank_w * 4)
+    assert b == 2 * (attn_b + bank_b)
+    f, b = work.bsmm_decode(c, 4)
+    assert f == 2 * 4 * T
+    assert b == 2 * 2 * (attn_w + bank_w) + 2 * (
+        4 * 512 * 2 + 4 * 0.5 * 4 * 768 * 2)
+    # decode at 200 cached keys: 200 in one layer, 128 in the other
+    f, b = work.decode_step(c, [(200, 0)])
+    assert f == 2 * (T + Hd) + 4 * 2 * 128 * (200 + 128)
+    assert b == 2 * (2 * (attn_w + bank_w) + Hd) + 2 * 128 * 2 * (200 + 128)
+    f, _ = work.prefill(c, 6)
+    assert f == 2 * 6 * T + 2 * Hd + 4 * 2 * 128 * (21 + 21)
+    # live score blocks of 256 tokens: 3 causal, and 3 under the window
+    f, b = work.flash_prefill(c, 256)
+    assert f == 4 * 2 * 128 * 6 * 128 * 128
+    assert b == 2 * (2 * 2 + 2 * 1) * 256 * 128 * 2
 
 
 def test_least_time_names_its_bound():

@@ -183,21 +183,30 @@ def _pool(block: int):
     import jax.numpy as jnp
 
     def pool(x):
-        a, c = x.shape
+        *lead, a, c = x.shape
         x = jnp.abs(x.astype(jnp.float32))
-        return jnp.sum(x.reshape(a // block, block, c // block, block),
-                       axis=(1, 3))
+        return jnp.sum(x.reshape(*lead, a // block, block, c // block, block),
+                       axis=(-3, -1))
 
     return jax.jit(pool)
 
 
 def pool_blocks(tree, block: int, only=None) -> dict:
-    """Per-leaf sums of |x| over ``block`` x ``block`` blocks of the 2-D
-    leaves of a pytree (those named in ``only``), on the device, keyed as
-    ``leaf_values``; dispatched without waiting."""
+    """Per-leaf sums of |x| over ``block`` x ``block`` blocks of the last
+    two dims, any leading dims kept (a bank (E, a, c) -> (E, a/b, c/b)), of
+    the leaves of a pytree (those named in ``only``), on the device, keyed
+    as ``leaf_values``; dispatched without waiting.  A leaf that does not
+    tile into such blocks is an error, never skipped."""
     pool = _pool(block)
-    return {k: pool(x) for k, x in leaf_values(tree).items()
-            if x.ndim == 2 and (only is None or k in only)}
+    out = {}
+    for k, x in leaf_values(tree).items():
+        if only is not None and k not in only:
+            continue
+        if x.ndim < 2 or x.shape[-2] % block or x.shape[-1] % block:
+            raise ValueError(f"chipbench: sparse leaf {k} of shape {x.shape} "
+                             f"does not tile into {block} x {block} blocks")
+        out[k] = pool(x)
+    return out
 
 
 def host_blocks(blocks: dict) -> dict:
@@ -222,8 +231,10 @@ def reference_update(before: dict, superset: dict, mags: dict,
     configuration states it: of each layer's n active blocks keep the
     n - k largest by summed |weight|, k = floor(fraction * n), then grow
     the k best by summed |gradient| among the rest of the backward
-    superset (freshly dropped blocks may grow again).  -> {leaf: (k, new
-    block mask)}."""
+    superset (freshly dropped blocks may grow again).  A leaf is one unit,
+    a bank of experts too: its blocks are ranked together with one k, as
+    the program's ``_drop_grow`` ranks them.  -> {leaf: (k, new block
+    mask)}."""
     out = {}
     for name, act in before.items():
         a = act.reshape(-1)
